@@ -29,16 +29,21 @@ from repro.core.spec import Trait, Workload
 # ---------------------------------------------------------------------------
 
 def filter_pipeline_sct(width: int = 1024) -> SCT:
-    """Gaussian Noise -> Solarize -> Mirror; epu = image line, nu = 2."""
+    """Gaussian Noise -> Solarize -> Mirror; epu = image line, nu = 2.
+
+    The noise hashes each pixel's global (row, col): a partition's first
+    row arrives through the OFFSET trait, so any split of the image gives
+    the whole-image result."""
     import jax.numpy as jnp
 
-    def noise(img):
-        h = (jnp.arange(img.shape[0])[:, None] * 31
-             + jnp.arange(img.shape[1])[None, :] * 17) % 13
+    def noise(img, row0):
+        rows = row0 + jnp.arange(img.shape[0])
+        h = (rows[:, None] * 31 + jnp.arange(img.shape[1])[None, :] * 17) % 13
         return jnp.clip(img + (h.astype(img.dtype) - 6.0), 0, 255)
 
     k1 = kernel(noise, name="gauss_noise",
-                inputs=[vector("img", epu=1)],
+                inputs=[vector("img", epu=1),
+                        scalar("row0", trait=Trait.OFFSET)],
                 outputs=[vector("noisy", epu=1)],  # 2 px/thread is intra-line
                 flops_per_item=6 * width, bytes_per_item=8 * width)
     k2 = kernel(lambda x: np_where_solarize(x), name="solarize",
@@ -85,8 +90,7 @@ def nbody_sct(n_bodies: int, iterations: int = 1) -> SCT:
         d = all_pos[None, :, :3] - mine[:, None, :3]
         r2 = (d * d).sum(-1) + 1e-3
         acc = (d / (r2 ** 1.5)[..., None]).sum(1)
-        return mine.at[:, :3].add(0.001 * acc) if hasattr(mine, "at") \
-            else mine
+        return jnp.asarray(mine).at[:, :3].add(0.001 * acc)
 
     body = kernel(step, name="nbody_step",
                   inputs=[vector("bodies", epu=1),
@@ -99,7 +103,8 @@ def nbody_sct(n_bodies: int, iterations: int = 1) -> SCT:
 
 
 def saxpy_sct() -> SCT:
-    k = kernel(lambda a, x, y: a * x + y, name="saxpy",
+    import jax.numpy as jnp
+    k = kernel(lambda a, x, y: jnp.multiply(a, x) + y, name="saxpy",
                inputs=[scalar("a"), vector("x", epu=1),
                        vector("y", epu=1)],
                outputs=[vector("z", epu=1)],
@@ -133,6 +138,84 @@ BENCHMARKS: Dict[str, Tuple] = {
     "segmentation": (lambda n: segmentation_sct(),
                      [64, 512, 3840], "planes (1Mpx)"),
 }
+
+
+# ---------------------------------------------------------------------------
+# Inputs and plain float32 references (NumPy, whole input, no scheduler)
+# ---------------------------------------------------------------------------
+
+SEG_PLANE = (1024, 1024)
+
+
+def make_inputs(name: str, size: int, seed: int = 0) -> Dict[str, np.ndarray]:
+    """Random float32 request arrays for one benchmark at ``size``."""
+    rng = np.random.default_rng(seed)
+    f32 = np.float32
+    if name == "filter_pipeline":
+        return {"img": rng.random((size, size), f32) * f32(255)}
+    if name == "fft":
+        return {"sig": rng.standard_normal((size, FFT_ELEMS), f32)}
+    if name == "nbody":
+        bodies = rng.standard_normal((size, 4), f32)
+        return {"bodies": bodies, "all_bodies": bodies}
+    if name == "saxpy":
+        return {"a": f32(2.5), "x": rng.standard_normal(size, f32),
+                "y": rng.standard_normal(size, f32)}
+    if name == "segmentation":
+        return {"vol": rng.random((size, *SEG_PLANE), f32) * f32(255)}
+    raise KeyError(name)
+
+
+def reference(name: str, inputs: Dict[str, np.ndarray]
+              ) -> Dict[str, np.ndarray]:
+    """Every output of the benchmark's SCT, computed in float32 NumPy."""
+    f32 = np.float32
+    if name == "filter_pipeline":
+        img = inputs["img"]
+        rows, cols = np.arange(img.shape[0]), np.arange(img.shape[1])
+        h = (rows[:, None] * 31 + cols[None, :] * 17) % 13
+        noisy = np.clip(img + (h.astype(f32) - f32(6)), 0, 255).astype(f32)
+        sol = np.where(noisy > 128, f32(255) - noisy, noisy)
+        return {"noisy": noisy, "sol": sol, "out": sol[:, ::-1]}
+    if name == "fft":
+        freq = np.real(np.fft.fft(inputs["sig"], axis=1)).astype(f32)
+        return {"freq": freq,
+                "sig_out": np.real(np.fft.ifft(freq, axis=1)).astype(f32)}
+    if name == "nbody":
+        mine, pos = inputs["bodies"], inputs["all_bodies"][:, :3]
+        out = mine.copy()
+        for i in range(0, len(mine), 512):      # bound the (i, j, 3) block
+            d = pos[None, :, :] - mine[i:i + 512, None, :3]
+            r2 = (d * d).sum(-1) + f32(1e-3)
+            acc = (d / (r2 ** f32(1.5))[..., None]).sum(1)
+            out[i:i + 512, :3] += f32(0.001) * acc
+        return {"bodies": out}
+    if name == "saxpy":
+        return {"z": inputs["a"] * inputs["x"] + inputs["y"]}
+    if name == "segmentation":
+        v = inputs["vol"]
+        return {"seg": np.where(v < 85, f32(0),
+                                np.where(v > 170, f32(255), f32(128)))}
+    raise KeyError(name)
+
+
+#: largest |got - ref| / max(|ref|max, 1) each benchmark may show: exact
+#: elementwise maps, summation order for the N-body sum and the FFTs
+TOLERANCE: Dict[str, float] = {"filter_pipeline": 1e-6, "fft": 1e-4,
+                               "nbody": 1e-4, "saxpy": 1e-6,
+                               "segmentation": 1e-6}
+
+
+def max_error(got: Dict[str, object], want: Dict[str, np.ndarray]) -> float:
+    """Worst normalised error over every reference output."""
+    worst = 0.0
+    for name, ref in want.items():
+        g = np.asarray(got[name], np.float32)
+        if g.shape != ref.shape:
+            return float("inf")
+        scale = max(float(np.abs(ref).max(initial=0.0)), 1.0)
+        worst = max(worst, float(np.abs(g - ref).max(initial=0.0)) / scale)
+    return worst
 
 
 # ---------------------------------------------------------------------------

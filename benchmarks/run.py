@@ -20,6 +20,8 @@ def main() -> int:
                     help="run a single module (e.g. 'hybrid')")
     args = ap.parse_args()
 
+    from repro.jaxcache import use_compile_cache
+    use_compile_cache()
     from benchmarks import (fission, hybrid, kb_derivation,
                             load_fluctuation, maxdev, profile_construction,
                             roofline)

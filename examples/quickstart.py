@@ -13,9 +13,12 @@ import numpy as np
 from repro.core import (AcceleratorPlatform, DeviceInfo, HostPlatform,
                         JobGraph, KnowledgeBase, Pipeline, Scheduler,
                         Session, ThreadedExecutor, kernel, scalar, vector)
+from repro.jaxcache import use_compile_cache
 
 
 def main():
+    use_compile_cache()
+
     # 1. Wrap kernels with their interfaces (paper Table 1): scale and
     #    shift share the vector edge "mid" -> the locality-aware
     #    decomposition partitions both identically, so "mid" never moves.

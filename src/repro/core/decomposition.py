@@ -34,7 +34,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -63,12 +63,15 @@ class ExecutionSlot:
 
     ``wgs``: work-group size chosen for each kernel on this slot's device
     (kernel name -> wgs).  ``device_type``: 'cpu' / 'gpu' / 'tpu' class
-    used by the workload-distribution generator.
+    used by the workload-distribution generator.  ``jax_device``: the
+    ``jax.Device`` the slot's kernels run on (``None``: JAX's default).
     """
 
     device: str
     device_type: str
     wgs: Dict[str, int] = dataclasses.field(default_factory=dict)
+    jax_device: Any = dataclasses.field(default=None, compare=False,
+                                        repr=False)
 
     def quantum(self, vectors: Sequence[VectorPlan],
                 specs: Sequence[KernelSpec]) -> int:

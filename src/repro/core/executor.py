@@ -52,6 +52,16 @@ Direct slot writes assume deterministic kernels (a timed-out slot retried
 elsewhere re-produces the same bytes); merged results are bit-identical
 to the historical ``np.concatenate`` merge.
 
+Device binding
+--------------
+A slot whose ``ExecutionSlot.jax_device`` is set runs ``sct.apply`` under
+``jax.default_device`` of that device — a thread-local setting, so
+concurrent slots do not interfere — and its JAX kernels compute there.
+Only the compute moves: inputs still arrive as host views, and outputs
+are merged into host buffers.  With telemetry on, each ``slot`` span
+notes ``bound`` (the slot's device) and ``placed`` (the devices that hold
+its outputs).
+
 Failure semantics
 -----------------
 Execution is tracked per *segment* — a contiguous domain-unit range bound
@@ -74,11 +84,13 @@ for concatenated outputs, and identical for associative merge functions.
 from __future__ import annotations
 
 import concurrent.futures as cf
+import contextlib
 import dataclasses
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple, Union
 
+import jax
 import numpy as np
 
 from repro.core.decomposition import ConcretePartitioning
@@ -584,6 +596,8 @@ class ThreadedExecutor:
         """Run one round of segments concurrently, containing all faults."""
         targets = targets or {}
         pool_sec = pool_sec if pool_sec is not None else [0.0]
+        traced = self.telemetry.tracer.enabled
+        produced = _produced_names(sct) if traced else []
 
         def work(seg: _Segment) -> Union[_SlotResult, FaultRecord]:
             slot = part.slots[seg.slot]
@@ -600,10 +614,16 @@ class ThreadedExecutor:
                         if kind == "stall":
                             time.sleep(self.injector.stall_seconds)
                     env = self._segment_env(part, arrays, seg, resident)
-                    out_env = sct.apply(env)
-                    for v in out_env.values():
-                        if hasattr(v, "block_until_ready"):
-                            v.block_until_ready()
+                    dev = slot.jax_device
+                    with (jax.default_device(dev) if dev is not None
+                          else contextlib.nullcontext()):
+                        out_env = sct.apply(env)
+                        for v in out_env.values():
+                            if hasattr(v, "block_until_ready"):
+                                v.block_until_ready()
+                    if traced:
+                        sp.note(bound=None if dev is None else str(dev),
+                                placed=_placement(out_env, produced))
                     written = self._direct_write(out_env, seg, targets)
                     return _SlotResult(out_env, time.perf_counter() - t0,
                                        written)
@@ -969,6 +989,19 @@ class ThreadedExecutor:
         return ResidentPartition(part=part, layout=layout, envs=envs,
                                  meta=meta, extras=extras,
                                  executor=self, sct=sct)
+
+
+def _placement(out_env: Dict[str, Any], names: Sequence[str]) -> List[str]:
+    """Sorted devices holding a slot's produced JAX outputs (host numpy
+    outputs contribute ``"host"``)."""
+    where = set()
+    for name in names:
+        v = out_env.get(name)
+        if isinstance(v, jax.Array):
+            where.update(str(d) for d in v.devices())
+        elif v is not None:
+            where.add("host")
+    return sorted(where)
 
 
 def _produced_names(sct: SCT) -> List[str]:

@@ -9,17 +9,14 @@ The Marrow runtime delegates device specificities to *execution platforms*:
   executions per GPU so communication overlaps computation, plus the
   occupancy-ordered work-group size candidates.
 
-TPU adaptation (see DESIGN.md Sec. 2):
+TPU adaptation:
 
-* :class:`HostPlatform` keeps the paper's fission semantics. Its affinity
-  levels map onto the ICI/host hierarchy of a TPU slice — fission level
-  ``L1`` = one execution slot per chip, ``L2`` = per pair, ``L3`` = per
-  host (8 chips), ``NUMA`` = per 32-chip island, ``NO_FISSION`` = the
-  whole slice as one slot.  On this CPU-only container the same levels
-  split the host cores' partition count for the real (timed) executor.
-* :class:`AcceleratorPlatform` maps overlap onto the in-flight microbatch
-  depth (GPU multi-buffering == TPU grad-accumulation chunks whose
-  collectives overlap the next chunk's compute).
+* :class:`HostPlatform` keeps the paper's fission semantics over the host
+  CPU; :meth:`HostPlatform.from_jax` binds it to JAX's CPU device, so host
+  slots really run on the CPU backend.
+* :class:`AcceleratorPlatform` is the fast class, one member per chip
+  (:meth:`AcceleratorPlatform.from_jax`); overlap is the number of
+  in-flight executions per chip (the paper's GPU multi-buffering).
 
 Install-time calibration (paper: SHOC suite) is
 :func:`AcceleratorPlatform.calibrate` — relative throughput scores that
@@ -28,8 +25,10 @@ drive the *static* intra-class distribution of Sec. 3.2.
 from __future__ import annotations
 
 import dataclasses
+import os
 import time
-from typing import Callable, Dict, Iterable, List, Optional, Sequence, Tuple
+from typing import (Any, Callable, Dict, Iterable, List, Optional, Sequence,
+                    Tuple)
 
 from repro.core.occupancy import BlockScore, candidates
 from repro.core.spec import KernelSpec
@@ -41,15 +40,41 @@ FISSION_LEVELS = ("L1", "L2", "L3", "NUMA", "NO_FISSION")
 
 @dataclasses.dataclass(frozen=True)
 class DeviceInfo:
-    """One schedulable device (or device class member)."""
+    """One schedulable device (or device class member).
+
+    ``jax_device`` binds the device to a real ``jax.Device``: slots built
+    from it run their kernels there.  Hand-built entries (tests, the
+    simulator) leave it ``None`` and run on JAX's default device; their
+    peaks are the analytic defaults below.  Entries built by
+    :meth:`AcceleratorPlatform.from_jax` / :meth:`HostPlatform.from_jax`
+    report the device's ``platform`` and ``device_kind`` and leave the
+    peaks unknown (``None``) rather than guessing them.
+    """
 
     name: str
-    kind: str                  # "cpu" | "gpu" | "tpu"
+    kind: str                  # scheduling class: "cpu" host, else accelerator
     compute_units: int = 1     # cores / chips in the device
-    peak_flops: float = 197e12     # bf16, TPU v5e default
-    hbm_bw: float = 819e9
-    link_bw: float = 50e9
+    peak_flops: Optional[float] = 197e12   # analytic default (v5e bf16)
+    hbm_bw: Optional[float] = 819e9
+    link_bw: Optional[float] = 50e9
     throughput: float = 1.0    # calibrated relative score (SHOC analogue)
+    jax_device: Any = dataclasses.field(default=None, compare=False,
+                                        repr=False)
+
+    @property
+    def platform(self) -> Optional[str]:
+        return getattr(self.jax_device, "platform", None)
+
+    @property
+    def device_kind(self) -> Optional[str]:
+        return getattr(self.jax_device, "device_kind", None)
+
+
+def _from_jax_device(name: str, kind: str, dev: Any,
+                     compute_units: int = 1) -> DeviceInfo:
+    return DeviceInfo(name, kind, compute_units=compute_units,
+                      peak_flops=None, hbm_bw=None, link_bw=None,
+                      jax_device=dev)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -76,6 +101,14 @@ class HostPlatform:
             "NUMA": max(cu // 16, 1), "NO_FISSION": 1,
         }
         self._level = "NO_FISSION"
+
+    @classmethod
+    def from_jax(cls) -> "HostPlatform":
+        """The host class bound to JAX's first CPU device, with one compute
+        unit per host core."""
+        import jax
+        dev = jax.devices("cpu")[0]
+        return cls(_from_jax_device("host", "cpu", dev, os.cpu_count() or 1))
 
     # paper: CPUExecutionPlatform.getConfigurations(SCT, args)
     def get_configurations(self, sct=None, arguments=None) -> List[FissionConfig]:
@@ -109,6 +142,22 @@ class AcceleratorPlatform:
         self.max_overlap = max_overlap
         self.occupancy_threshold = occupancy_threshold
         self._overlap = 1
+
+    @classmethod
+    def from_jax(cls, devices: Optional[Sequence[Any]] = None
+                 ) -> "AcceleratorPlatform":
+        """One class member per ``jax.Device`` (default: ``jax.devices()``).
+
+        Members are named ``<platform><id>``.  A CPU device standing in for
+        an accelerator (virtual devices in tests) is named and classed
+        ``accel``, so the scheduler keeps it out of the host class."""
+        import jax
+        devs = list(jax.devices() if devices is None else devices)
+        infos = []
+        for d in devs:
+            kind = "accel" if d.platform == "cpu" else d.platform
+            infos.append(_from_jax_device(f"{kind}{d.id}", kind, d))
+        return cls(infos)
 
     # paper: GPUExecutionPlatform.getConfigurations -> ({overlaps}, {wgs})
     def get_configurations(self, sct=None, arguments=None,
@@ -146,13 +195,17 @@ class AcceleratorPlatform:
                   ) -> List[float]:
         """Relative throughput per device, for the static intra-class split.
 
-        With no measurable hardware (CPU-only container) the calibration
-        falls back to the analytic model: peak_flops as the score.  When a
-        ``workload`` timer is supplied (real hardware), scores are the
-        inverse measured times.
+        Without a ``workload`` timer the scores are analytic: peak_flops
+        times throughput, or throughput alone when any member's peak is
+        unknown (devices built by :meth:`from_jax`) — an even split for
+        identical devices.  When a ``workload`` timer is supplied (real
+        hardware), scores are the inverse measured times.
         """
         if workload is None:
-            scores = [d.peak_flops * d.throughput for d in self.devices]
+            if any(d.peak_flops is None for d in self.devices):
+                scores = [d.throughput for d in self.devices]
+            else:
+                scores = [d.peak_flops * d.throughput for d in self.devices]
         else:
             times = [max(workload(d), 1e-12) for d in self.devices]
             scores = [1.0 / t for t in times]

@@ -1232,12 +1232,15 @@ class Scheduler:
             for o in range(self.accel.overlap):
                 slots.append(ExecutionSlot(device=f"{d.name}/q{o}",
                                            device_type=d.kind,
-                                           wgs=dict(profile.config.wgs)))
-        if self.health.usable(self.host.device.name):
+                                           wgs=dict(profile.config.wgs),
+                                           jax_device=d.jax_device))
+        host = self.host.device
+        if self.health.usable(host.name):
             for i in range(self.host.parallelism):
                 slots.append(ExecutionSlot(
-                    device=f"{self.host.device.name}/f{i}",
-                    device_type="cpu", wgs=dict(profile.config.wgs)))
+                    device=f"{host.name}/f{i}", device_type="cpu",
+                    wgs=dict(profile.config.wgs),
+                    jax_device=host.jax_device))
         if not slots:
             raise ExecutionError(
                 "all devices quarantined: no execution slots available "

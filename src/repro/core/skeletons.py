@@ -218,10 +218,21 @@ class Loop(SCT):
     def apply(self, env: Env) -> Env:
         env = dict(env)
         env.update(self.state.init)
-        env = _ensure_body_outputs(self.body, env, self.state)
+        # partition info is static: close over it, never carry it
+        info = env.pop("__partition__", None)
+
+        def body_apply(e: Env) -> Env:
+            e = dict(e)
+            if info is not None:
+                e["__partition__"] = info
+            e = self.body.apply(e)
+            e.pop("__partition__", None)
+            return e
+
+        env = _ensure_body_outputs(body_apply, env)
 
         def one_iter(e: Env) -> Env:
-            e = self.body.apply(dict(e))
+            e = body_apply(e)
             if self.state.update is not None:
                 e = self.state.update(e)
             return e
@@ -230,7 +241,9 @@ class Loop(SCT):
             # static trip-count for loop
             def body_fun(_, e):
                 return one_iter(e)
-            return jax.lax.fori_loop(0, self.state.max_iterations, body_fun, env)
+            env = jax.lax.fori_loop(0, self.state.max_iterations, body_fun,
+                                    env)
+            return _restore_partition(env, info)
 
         counter_key = "__loop_iters__"
         env[counter_key] = jnp.zeros((), jnp.int32)
@@ -248,13 +261,18 @@ class Loop(SCT):
 
         env = jax.lax.while_loop(cond_fun, body_fun, env)
         env.pop(counter_key, None)
-        return env
+        return _restore_partition(env, info)
 
 
-def _ensure_body_outputs(body: SCT, env: Env, state: LoopState) -> Env:
+def _restore_partition(env: Env, info: Optional[PartitionInfo]) -> Env:
+    if info is not None:
+        env["__partition__"] = info
+    return env
+
+
+def _ensure_body_outputs(body_apply: Callable[[Env], Env], env: Env) -> Env:
     """Pre-materialise body outputs so the while_loop carry is shape-stable."""
-    probe = dict(env)
-    shapes = jax.eval_shape(lambda e: body.apply(dict(e)), probe)
+    shapes = jax.eval_shape(body_apply, dict(env))
     for k, sd in shapes.items():
         if k not in env:
             env[k] = jnp.zeros(sd.shape, sd.dtype)

@@ -6,7 +6,7 @@ Layout:
   moe_gemm.py         grouped expert GEMM (MegaBlocks-style)
   saxpy.py, filter_pipeline.py, segmentation.py, nbody.py
                       the paper's own benchmark suite (Sec. 4)
-  ops.py              jit'd wrappers (interpret=True off-TPU)
+  ops.py              wrappers; interpret=True only when the caller asks
   ref.py              pure-jnp oracles for allclose tests
 """
 from repro.kernels import ops, ref

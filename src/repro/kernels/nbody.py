@@ -8,7 +8,6 @@ on TPU the j tile streams through the VPU at 8x128 lanes).
 """
 from __future__ import annotations
 
-import functools
 from typing import Tuple
 
 import jax
@@ -19,8 +18,7 @@ from jax.experimental.pallas import tpu as pltpu
 SOFTENING = 1e-3
 
 
-def _nbody_kernel(pos_i_ref, mass_all_ref, pos_all_ref, acc_out_ref,
-                  acc_ref, *, block_j: int):
+def _nbody_kernel(pos_i_ref, mass_ref, pos_jt_ref, acc_out_ref, acc_ref):
     jb = pl.program_id(1)
     nj = pl.num_programs(1)
 
@@ -29,12 +27,14 @@ def _nbody_kernel(pos_i_ref, mass_all_ref, pos_all_ref, acc_out_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     pi = pos_i_ref[...]                                  # (bi, 3)
-    pj = pos_all_ref[...]                                # (bj, 3)
-    mj = mass_all_ref[...]                               # (bj,)
-    d = pj[None, :, :] - pi[:, None, :]                  # (bi, bj, 3)
-    r2 = (d * d).sum(-1) + SOFTENING
-    inv_r3 = jax.lax.rsqrt(r2) / r2
-    acc_ref[...] += jnp.einsum("ij,ijk->ik", mj[None, :] * inv_r3, d)
+    pjt = pos_jt_ref[...]                                # (3, bj)
+    mj = mass_ref[...]                                   # (1, bj)
+    # one (bi, bj) plane per component: bodies j on lanes, i on sublanes
+    d = [pjt[c:c + 1, :] - pi[:, c:c + 1] for c in range(3)]
+    r2 = d[0] * d[0] + d[1] * d[1] + d[2] * d[2] + SOFTENING
+    w = mj * (jax.lax.rsqrt(r2) / r2)
+    acc_ref[...] += jnp.concatenate(
+        [jnp.sum(w * dc, axis=1, keepdims=True) for dc in d], axis=1)
 
     @pl.when(jb == nj - 1)
     def _emit():
@@ -50,23 +50,23 @@ def nbody_accelerations(pos: jax.Array, mass: jax.Array, *,
     ni, nj = -(-N // bi), -(-N // bj)
     pad_i, pad_j = ni * bi - N, nj * bj - N
     pos_i = jnp.pad(pos, ((0, pad_i), (0, 0))) if pad_i else pos
-    pos_j = jnp.pad(pos, ((0, pad_j), (0, 0))) if pad_j else pos
-    mass_j = jnp.pad(mass, (0, pad_j)) if pad_j else mass  # padded m=0: no force
+    pos_jt = (jnp.pad(pos, ((0, pad_j), (0, 0))) if pad_j else pos).T
+    # padded m=0: no force
+    mass_j = (jnp.pad(mass, (0, pad_j)) if pad_j else mass).reshape(1, -1)
 
-    kernel = functools.partial(_nbody_kernel, block_j=bj)
     acc = pl.pallas_call(
-        kernel,
+        _nbody_kernel,
         grid=(ni, nj),
         in_specs=[
             pl.BlockSpec((bi, 3), lambda i, j: (i, 0)),
-            pl.BlockSpec((bj,), lambda i, j: (j,)),
-            pl.BlockSpec((bj, 3), lambda i, j: (j, 0)),
+            pl.BlockSpec((1, bj), lambda i, j: (0, j)),
+            pl.BlockSpec((3, bj), lambda i, j: (0, j)),
         ],
         out_specs=pl.BlockSpec((bi, 3), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((ni * bi, 3), pos.dtype),
         scratch_shapes=[pltpu.VMEM((bi, 3), jnp.float32)],
         interpret=interpret,
-    )(pos_i, mass_j, pos_j)
+    )(pos_i, mass_j, pos_jt)
     return acc[:N]
 
 

@@ -1,9 +1,10 @@
 """Pallas Segmentation — the paper's 3-D Map benchmark.
 
 Gray-scale volume -> {black, gray, white} by two thresholds.  The
-elementary partitioning unit is one (D1 x D2) plane (paper Sec. 4:
-"partitioning can be performed only over the last [dimension]"), so the
-block is a whole plane and the grid walks dim 2.
+elementary partitioning unit is one (D2 x D3) plane, and planes lie on
+axis 0, as in the paper workload (``benchmarks/paper_suite.py``).  The
+grid walks the planes, and each plane in bands of ``block_rows`` rows,
+so a block is (1, rows, D3) with the plane's rows and columns last.
 """
 from __future__ import annotations
 
@@ -21,15 +22,17 @@ def _seg_kernel(vol_ref, o_ref, *, lo: float, hi: float):
 
 
 def segmentation(vol: jax.Array, *, lo: float = 85.0, hi: float = 170.0,
-                 interpret: bool = False) -> jax.Array:
-    """vol (D1, D2, D3) f32 -> segmented volume (plane-partitioned)."""
+                 block_rows: int = 256, interpret: bool = False) -> jax.Array:
+    """vol (planes, D2, D3) f32 -> segmented volume (plane-partitioned)."""
     D1, D2, D3 = vol.shape
+    br = min(block_rows, D2)
     kernel = functools.partial(_seg_kernel, lo=lo, hi=hi)
+    block = pl.BlockSpec((1, br, D3), lambda p, i: (p, i, 0))
     return pl.pallas_call(
         kernel,
-        grid=(D3,),
-        in_specs=[pl.BlockSpec((D1, D2, 1), lambda i: (0, 0, i))],
-        out_specs=pl.BlockSpec((D1, D2, 1), lambda i: (0, 0, i)),
+        grid=(D1, -(-D2 // br)),
+        in_specs=[block],
+        out_specs=block,
         out_shape=jax.ShapeDtypeStruct((D1, D2, D3), vol.dtype),
         interpret=interpret,
     )(vol)

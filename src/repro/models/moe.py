@@ -22,9 +22,8 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from repro.compat import shard_map
 
 from repro.models.config import ModelConfig
 from repro.models.layers import Defs, ParamDef, activate, softcap

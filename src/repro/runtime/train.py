@@ -174,7 +174,7 @@ def make_dp_train_step_int8(cfg: ModelConfig, optimizer: AdamW,
     per-shard quantisation error is carried in the error-feedback state so
     the accumulated update stays unbiased.
     """
-    from repro.compat import shard_map
+    from jax import shard_map
 
     loss_fn = make_loss_fn(cfg, rt)
     n = mesh.shape[axis]

@@ -6,9 +6,6 @@ sharing it:  epu(V) % nu(V,K) == 0,  #V^j % (epu/nu) == 0,
 """
 import jax.numpy as jnp
 import pytest
-
-pytest.importorskip("hypothesis", reason="property tests need hypothesis "
-                    "(pip install -r requirements-dev.txt)")
 from hypothesis import given, settings, strategies as st
 
 from repro.core import (DecompositionError, ExecutionSlot, KernelSpec,

@@ -3,9 +3,6 @@
 import math
 
 import pytest
-
-pytest.importorskip("hypothesis", reason="property tests need hypothesis "
-                    "(pip install -r requirements-dev.txt)")
 from hypothesis import given, settings, strategies as st
 
 from repro.core import (AdaptiveBinarySearch, Distribution,

@@ -44,7 +44,8 @@ def test_flash_attention_variants(shape, variant):
         kw = dict(causal=True, logit_cap=20.0)
     elif variant == "window+cap":
         kw = dict(causal=True, window=24, logit_cap=30.0)
-    got = ops.flash_attention(q, kk, v, block_q=32, block_k=32, **kw)
+    got = ops.flash_attention(q, kk, v, block_q=32, block_k=32,
+                              interpret=True, **kw)
     want = ref.attention_ref(q, kk, v, **kw)
     assert_allclose(got, want, rtol=3e-4, atol=3e-4)
 
@@ -55,7 +56,8 @@ def test_flash_attention_dtypes(dtype):
     q = jax.random.normal(k(4), (B, H, S, hd), dtype)
     kk = jax.random.normal(k(5), (B, KV, S, hd), dtype)
     v = jax.random.normal(k(6), (B, KV, S, hd), dtype)
-    got = ops.flash_attention(q, kk, v, block_q=32, block_k=32)
+    got = ops.flash_attention(q, kk, v, block_q=32, block_k=32,
+                              interpret=True)
     want = ref.attention_ref(q, kk, v)
     tol = 3e-4 if dtype == jnp.float32 else 3e-2
     assert_allclose(got.astype(np.float32), want.astype(np.float32),
@@ -69,7 +71,7 @@ def test_flash_attention_kv_len_mask():
     kk = jax.random.normal(k(8), (B, KV, S, hd), jnp.float32)
     v = jax.random.normal(k(9), (B, KV, S, hd), jnp.float32)
     got = ops.flash_attention(q, kk, v, kv_len=40, causal=False,
-                              block_q=32, block_k=32)
+                              block_q=32, block_k=32, interpret=True)
     want = ref.attention_ref(q[:, :, :, :], kk[:, :, :40], v[:, :, :40],
                              causal=False)
     assert_allclose(got, want, rtol=3e-4, atol=3e-4)
@@ -85,7 +87,7 @@ def test_flash_matches_model_oracle():
     want = blockwise_attention(q, kk, v, causal=True, q_block=32,
                                k_block=32)
     got = ops.flash_attention_bshd(q, kk, v, causal=True, block_q=32,
-                                   block_k=32)
+                                   block_k=32, interpret=True)
     assert_allclose(got, want, rtol=3e-4, atol=3e-4)
 
 
@@ -109,7 +111,7 @@ def test_ssd_scan_shapes(shape):
     Bm = jax.random.normal(k(22), (B, S, ds)) * 0.5
     Cm = jax.random.normal(k(23), (B, S, ds)) * 0.5
     A = -jnp.exp(jax.random.normal(k(24), (nh,)) * 0.3)
-    y1, h1 = ops.ssd_scan(x, dt, Bm, Cm, A, chunk=chunk)
+    y1, h1 = ops.ssd_scan(x, dt, Bm, Cm, A, chunk=chunk, interpret=True)
     y2, h2 = ref.ssd_scan_ref(x, dt, Bm, Cm, A, chunk=chunk)
     assert_allclose(y1, y2, rtol=3e-4, atol=3e-4)
     assert_allclose(h1, h2, rtol=3e-4, atol=3e-4)
@@ -123,11 +125,12 @@ def test_ssd_scan_state_chaining():
     Bm = jax.random.normal(k(27), (B, S, ds)) * 0.5
     Cm = jax.random.normal(k(28), (B, S, ds)) * 0.5
     A = -jnp.exp(jax.random.normal(k(29), (nh,)) * 0.3)
-    y_full, h_full = ops.ssd_scan(x, dt, Bm, Cm, A, chunk=chunk)
+    y_full, h_full = ops.ssd_scan(x, dt, Bm, Cm, A, chunk=chunk,
+                                  interpret=True)
     y1, h1 = ops.ssd_scan(x[:, :16], dt[:, :16], Bm[:, :16], Cm[:, :16],
-                          A, chunk=chunk)
+                          A, chunk=chunk, interpret=True)
     y2, h2 = ops.ssd_scan(x[:, 16:], dt[:, 16:], Bm[:, 16:], Cm[:, 16:],
-                          A, chunk=chunk, h0=h1)
+                          A, chunk=chunk, h0=h1, interpret=True)
     assert_allclose(jnp.concatenate([y1, y2], 1), y_full, rtol=3e-4,
                     atol=3e-4)
     assert_allclose(h2, h_full, rtol=3e-4, atol=3e-4)
@@ -144,7 +147,8 @@ def test_grouped_matmul(shape, dtype):
     E, C, d, f = shape
     x = jax.random.normal(k(30), (E, C, d), dtype)
     w = jax.random.normal(k(31), (E, d, f), dtype)
-    got = ops.grouped_matmul(x, w, block_c=16, block_f=16, block_d=32)
+    got = ops.grouped_matmul(x, w, block_c=16, block_f=16, block_d=32,
+                             interpret=True)
     want = ref.grouped_matmul_ref(x, w)
     tol = 2e-4 if dtype == jnp.float32 else 3e-2
     assert_allclose(got.astype(np.float32), want.astype(np.float32),
@@ -159,7 +163,7 @@ def test_grouped_matmul(shape, dtype):
 def test_saxpy(n):
     x = jax.random.normal(k(40), (n,))
     y = jax.random.normal(k(41), (n,))
-    assert_allclose(ops.saxpy(2.5, x, y, block=256),
+    assert_allclose(ops.saxpy(2.5, x, y, block=256, interpret=True),
                     ref.saxpy_ref(2.5, x, y), rtol=1e-5, atol=1e-5)
 
 
@@ -167,14 +171,15 @@ def test_saxpy(n):
 def test_filter_pipeline(hw):
     H, W = hw
     img = jax.random.uniform(k(42), (H, W)) * 255
-    got = ops.filter_pipeline(img, seed=3, block_rows=16)
+    got = ops.filter_pipeline(img, seed=3, block_rows=16,
+                              interpret=True)
     want = ref.filter_pipeline_ref(img, seed=3)
     assert_allclose(got, want, rtol=1e-5, atol=1e-4)
 
 
 def test_filter_pipeline_is_mirrored():
     img = jnp.tile(jnp.arange(16.0)[None, :], (4, 1))
-    out = ops.filter_pipeline(img, noise_scale=0.0)
+    out = ops.filter_pipeline(img, noise_scale=0.0, interpret=True)
     # column order must be reversed (values change via solarize only)
     assert float(out[0, 0]) >= float(out[0, -1])
 
@@ -182,7 +187,7 @@ def test_filter_pipeline_is_mirrored():
 @pytest.mark.parametrize("shape", [(8, 8, 4), (16, 24, 5), (32, 8, 3)])
 def test_segmentation(shape):
     v = jax.random.uniform(k(43), shape) * 255
-    got = ops.segmentation(v)
+    got = ops.segmentation(v, interpret=True)
     want = ref.segmentation_ref(v)
     assert_allclose(got, want)
     assert set(np.unique(np.asarray(got))) <= {0.0, 128.0, 255.0}
@@ -192,7 +197,8 @@ def test_segmentation(shape):
 def test_nbody(n):
     pos = jax.random.normal(k(44), (n, 3))
     mass = jax.random.uniform(k(45), (n,)) + 0.1
-    got = ops.nbody_accelerations(pos, mass, block_i=32, block_j=64)
+    got = ops.nbody_accelerations(pos, mass, block_i=32, block_j=64,
+                                  interpret=True)
     want = ref.nbody_ref(pos, mass)
     assert_allclose(got, want, rtol=3e-4, atol=3e-4)
 
@@ -205,6 +211,6 @@ def test_nbody_energy_behaviour():
     mass = jnp.ones((n,))
     p, v = pos, vel
     for _ in range(3):
-        p, v = ops.nbody_step(p, v, mass, dt=1e-3)
+        p, v = ops.nbody_step(p, v, mass, dt=1e-3, interpret=True)
     total_momentum = np.asarray((mass[:, None] * v).sum(0))
     assert np.abs(total_momentum).max() < 1e-2

@@ -5,9 +5,6 @@ import os
 
 import numpy as np
 import pytest
-
-pytest.importorskip("hypothesis", reason="property tests need hypothesis "
-                    "(pip install -r requirements-dev.txt)")
 from hypothesis import given, settings, strategies as st
 
 from repro.core import KnowledgeBase, Origin, PlatformConfig, Profile

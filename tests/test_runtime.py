@@ -6,9 +6,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-
-pytest.importorskip("hypothesis", reason="property tests need hypothesis "
-                    "(pip install -r requirements-dev.txt)")
 from hypothesis import given, settings, strategies as st
 from numpy.testing import assert_allclose
 

@@ -8,6 +8,7 @@ from repro.core import (AcceleratorPlatform, DeviceInfo, HostPlatform,
                         KernelSpec, KnowledgeBase, Loop, LoopState, Map,
                         MapReduce, MERGE_ADD, Pipeline, Scheduler, Session,
                         ThreadedExecutor, Trait, kernel, scalar, vector)
+from repro.core.skeletons import PartitionInfo
 
 
 def saxpy_tree():
@@ -53,6 +54,20 @@ class TestSkeletons:
                    outputs=[vector("y")])
         env = k.apply({"x": jnp.zeros(8)})
         assert float(env["y"][0]) == 8.0      # size=8, offset=0
+
+    def test_loop_sees_partition_info(self):
+        """A slot's partition info reaches kernels inside a Loop and is
+        never carried through the loop as an array."""
+        body = kernel(lambda x, off: x + off, name="shift",
+                      inputs=[vector("x"), scalar("off", trait=Trait.OFFSET)],
+                      outputs=[vector("x")])
+        info = PartitionInfo(size=4, offset=10)
+        for state in (LoopState(max_iterations=2),
+                      LoopState(cond=lambda e: e["x"][0] < 20)):
+            env = Loop(body, state).apply(
+                {"x": jnp.zeros(4), "__partition__": info})
+            assert float(env["x"][0]) == 20.0
+            assert env["__partition__"] is info
 
     def test_unique_id_structural(self):
         a = Pipeline(saxpy_tree())
