@@ -8,10 +8,10 @@ Each paper SCT is submitted through ``Session.submit`` (``Session`` ->
 ``Scheduler`` -> ``ThreadedExecutor``), with the accelerator class built
 from the real TPU devices and the host class from JAX's CPU device, three
 requests each, and every output is compared with a plain float32 NumPy
-reference on the whole input.  Each SCT gets a fresh Scheduler: a shared
-knowledge base would derive one SCT's first split from another SCT's
-profiles, which can leave the chip almost no work.  Then the four paper Pallas kernels run
-compiled (not interpreted) and are compared with ``repro.kernels.ref``.
+reference on the whole input.  One Session serves all five SCTs, one
+after another, as it would serve a user.  Then the four paper Pallas
+kernels run compiled (not interpreted) and are compared with
+``repro.kernels.ref``.
 
 The run fails on any slot fault, retry, quarantined device, zero
 accelerator share, or a slot whose outputs live on a device other than
@@ -26,7 +26,7 @@ import json
 import os
 import sys
 import time
-from typing import Any, Dict, List, Sequence, Tuple
+from typing import Any, Dict, List, Tuple
 
 ROOT = os.path.dirname(os.path.abspath(__file__))
 
@@ -52,14 +52,14 @@ def _import_repo():
             sys.path.insert(0, p)
 
 
-def build_session(accel_devices: Sequence[Any]):
-    """Session over a Scheduler whose accelerator class is
-    ``accel_devices`` and whose host class is JAX's CPU device."""
-    from repro.core import (AcceleratorPlatform, HostPlatform, KnowledgeBase,
-                            Scheduler, Session, Telemetry, ThreadedExecutor)
+def build_session(accel):
+    """Session over a Scheduler whose accelerator class is the
+    ``AcceleratorPlatform`` ``accel`` and whose host class is JAX's CPU
+    device."""
+    from repro.core import (HostPlatform, KnowledgeBase, Scheduler, Session,
+                            Telemetry, ThreadedExecutor)
     telemetry = Telemetry()
-    sched = Scheduler(host=HostPlatform.from_jax(),
-                      accel=AcceleratorPlatform.from_jax(accel_devices),
+    sched = Scheduler(host=HostPlatform.from_jax(), accel=accel,
                       executor=ThreadedExecutor(), kb=KnowledgeBase(),
                       telemetry=telemetry)
     return Session(sched), telemetry
@@ -215,16 +215,18 @@ def kernel_phase(name: str, size: int, device, *, interpret: bool = False,
             "tol": KERNEL_TOLERANCE[name], "device": placed[0]}
 
 
-def four_chip_phase(name: str, size: int, devices: Sequence[Any], *,
+def four_chip_phase(name: str, size: int, accel, *,
                     requests: int = REQUESTS, seed: int = 0
                     ) -> Dict[str, Any]:
-    """The same requests with the accelerator class over all ``devices``
-    and over ``devices[0]`` alone: both legs must match the reference and
-    each other, and the wide leg must use every device."""
+    """The same requests with the accelerator class ``accel`` over all its
+    devices and over its first device alone: both legs must match the
+    reference and each other, and the wide leg must use every device."""
     from benchmarks.paper_suite import TOLERANCE, max_error
+    from repro.core import AcceleratorPlatform
+    devices = accel.devices
     legs = {}
-    for label, devs in (("all", list(devices)), ("chip0", [devices[0]])):
-        session, telemetry = build_session(devs)
+    for label, members in (("all", devices), ("chip0", devices[:1])):
+        session, telemetry = build_session(AcceleratorPlatform(members))
         with session:
             legs[label] = sct_phase(session, telemetry, name, size,
                                     requests=requests, seed=seed,
@@ -257,6 +259,7 @@ def main(argv=None) -> int:
               file=sys.stderr)
         return 2
     _import_repo()
+    from repro.core import AcceleratorPlatform
     from repro.jaxcache import use_compile_cache
     use_compile_cache()
 
@@ -268,12 +271,14 @@ def main(argv=None) -> int:
     try:
         if args.chips == 4:
             for name in FOUR_CHIP_SCTS:
-                print(json.dumps(four_chip_phase(name, SIZES[name],
-                                                 devices[:4])), flush=True)
+                accel = AcceleratorPlatform.from_jax(devices[:4])
+                print(json.dumps(four_chip_phase(name, SIZES[name], accel)),
+                      flush=True)
         else:
-            for name, size in SIZES.items():
-                session, telemetry = build_session(devices[:1])
-                with session:
+            session, telemetry = build_session(
+                AcceleratorPlatform.from_jax(devices[:1]))
+            with session:
+                for name, size in SIZES.items():
                     print(json.dumps(sct_phase(session, telemetry, name,
                                                size)), flush=True)
             for name, size in KERNEL_SIZES.items():

@@ -63,15 +63,16 @@ class ExecutionSlot:
 
     ``wgs``: work-group size chosen for each kernel on this slot's device
     (kernel name -> wgs).  ``device_type``: 'cpu' / 'gpu' / 'tpu' class
-    used by the workload-distribution generator.  ``jax_device``: the
-    ``jax.Device`` the slot's kernels run on (``None``: JAX's default).
+    used by the workload-distribution generator.  ``info``: the
+    :class:`~repro.core.platforms.DeviceInfo` the slot was built from; its
+    ``jax_device`` is where the slot's kernels run (``None``: JAX's
+    default device).
     """
 
     device: str
     device_type: str
     wgs: Dict[str, int] = dataclasses.field(default_factory=dict)
-    jax_device: Any = dataclasses.field(default=None, compare=False,
-                                        repr=False)
+    info: Any = dataclasses.field(default=None, compare=False, repr=False)
 
     def quantum(self, vectors: Sequence[VectorPlan],
                 specs: Sequence[KernelSpec]) -> int:

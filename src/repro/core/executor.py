@@ -54,9 +54,10 @@ to the historical ``np.concatenate`` merge.
 
 Device binding
 --------------
-A slot whose ``ExecutionSlot.jax_device`` is set runs ``sct.apply`` under
-``jax.default_device`` of that device — a thread-local setting, so
-concurrent slots do not interfere — and its JAX kernels compute there.
+A slot whose device (``ExecutionSlot.info.jax_device``) is set runs
+``sct.apply`` under ``jax.default_device`` of that device — a
+thread-local setting, so concurrent slots do not interfere — and its JAX
+kernels compute there.
 Only the compute moves: inputs still arrive as host views, and outputs
 are merged into host buffers.  With telemetry on, each ``slot`` span
 notes ``bound`` (the slot's device) and ``placed`` (the devices that hold
@@ -614,7 +615,7 @@ class ThreadedExecutor:
                         if kind == "stall":
                             time.sleep(self.injector.stall_seconds)
                     env = self._segment_env(part, arrays, seg, resident)
-                    dev = slot.jax_device
+                    dev = slot.info.jax_device if slot.info else None
                     with (jax.default_device(dev) if dev is not None
                           else contextlib.nullcontext()):
                         out_env = sct.apply(env)

@@ -108,11 +108,16 @@ class Profile:
 # ---------------------------------------------------------------------------
 
 class RBFNetwork:
-    """Regularised Gaussian radial-basis-function network.
+    """Regularised Gaussian radial-basis-function network with a constant
+    term.
 
-    phi(r) = exp(-(r/sigma)^2); weights from the regularised linear solve
-    (Phi + lam*I) w = y.  Features are standardised (zero mean / unit std)
-    before fitting — workload dims span orders of magnitude.
+    f(x) = c + sum_i w_i phi(|x - x_i|), phi(r) = exp(-(r/sigma)^2), with
+    c the mean of the targets and w from the regularised linear solve
+    (Phi + lam*I) w = y - c.  Far from every node the Gaussians vanish and
+    f falls back to c, not to 0: a workload unlike any profile gets the
+    profiles' mean share, never a share that no profile supports.
+    Features are standardised (zero mean / unit std) before fitting —
+    workload dims span orders of magnitude.
     """
 
     def __init__(self, sigma: Optional[float] = None, lam: float = 1e-8):
@@ -122,6 +127,7 @@ class RBFNetwork:
         self._w: Optional[np.ndarray] = None
         self._mu: Optional[np.ndarray] = None
         self._sd: Optional[np.ndarray] = None
+        self._c = 0.0
 
     def fit(self, x: np.ndarray, y: np.ndarray) -> "RBFNetwork":
         x = np.asarray(x, dtype=np.float64)
@@ -141,7 +147,8 @@ class RBFNetwork:
                 self.sigma = 1.0
         phi = self._phi(xs, xs)
         n = len(xs)
-        self._w = np.linalg.solve(phi + self.lam * np.eye(n), y)
+        self._c = float(y.mean())
+        self._w = np.linalg.solve(phi + self.lam * np.eye(n), y - self._c)
         self._x = xs
         return self
 
@@ -155,7 +162,7 @@ class RBFNetwork:
         if one:
             x = x[None, :]
         xs = (x - self._mu) / self._sd
-        out = self._phi(xs, self._x) @ self._w
+        out = self._c + self._phi(xs, self._x) @ self._w
         return out[0] if one else out
 
 

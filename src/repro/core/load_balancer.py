@@ -165,7 +165,9 @@ class LoadBalancer:
             share_a_after=round(new.a, 6), time_a=stats_a, time_b=stats_b)
         return new
 
-    def reset_search(self) -> None:
+    def reset(self) -> None:
+        """Forget the history: lbt back to 0 and no search in progress."""
+        self.lbt = 0.0
         self._search = None
 
     def balanced_again(self) -> None:

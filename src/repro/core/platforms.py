@@ -47,8 +47,8 @@ class DeviceInfo:
     simulator) leave it ``None`` and run on JAX's default device; their
     peaks are the analytic defaults below.  Entries built by
     :meth:`AcceleratorPlatform.from_jax` / :meth:`HostPlatform.from_jax`
-    report the device's ``platform`` and ``device_kind`` and leave the
-    peaks unknown (``None``) rather than guessing them.
+    keep the device, whose ``platform`` and ``device_kind`` say what it
+    is, and leave the peaks unknown (``None``) rather than guessing them.
     """
 
     name: str
@@ -60,14 +60,6 @@ class DeviceInfo:
     throughput: float = 1.0    # calibrated relative score (SHOC analogue)
     jax_device: Any = dataclasses.field(default=None, compare=False,
                                         repr=False)
-
-    @property
-    def platform(self) -> Optional[str]:
-        return getattr(self.jax_device, "platform", None)
-
-    @property
-    def device_kind(self) -> Optional[str]:
-        return getattr(self.jax_device, "device_kind", None)
 
 
 def _from_jax_device(name: str, kind: str, dev: Any,
@@ -146,18 +138,12 @@ class AcceleratorPlatform:
     @classmethod
     def from_jax(cls, devices: Optional[Sequence[Any]] = None
                  ) -> "AcceleratorPlatform":
-        """One class member per ``jax.Device`` (default: ``jax.devices()``).
-
-        Members are named ``<platform><id>``.  A CPU device standing in for
-        an accelerator (virtual devices in tests) is named and classed
-        ``accel``, so the scheduler keeps it out of the host class."""
+        """One class member per ``jax.Device`` (default: ``jax.devices()``),
+        named ``<platform><id>`` and classed by its platform."""
         import jax
         devs = list(jax.devices() if devices is None else devices)
-        infos = []
-        for d in devs:
-            kind = "accel" if d.platform == "cpu" else d.platform
-            infos.append(_from_jax_device(f"{kind}{d.id}", kind, d))
-        return cls(infos)
+        return cls([_from_jax_device(f"{d.platform}{d.id}", d.platform, d)
+                    for d in devs])
 
     # paper: GPUExecutionPlatform.getConfigurations -> ({overlaps}, {wgs})
     def get_configurations(self, sct=None, arguments=None,

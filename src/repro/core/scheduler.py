@@ -460,6 +460,10 @@ class Scheduler:
                 with self._lock:        # decision phase (Fig. 4)
                     self._counts["decide_locks"] += 1
                     if key != self._last_key or self._current is None:
+                        # the detector's history describes the previous
+                        # (SCT, workload): it must not trigger an
+                        # adjustment of this one
+                        self.balancer.reset()
                         profile, action = self._derive(sct, workload)
                     else:
                         profile, action = self._recurrent(sct, workload)
@@ -1126,8 +1130,7 @@ class Scheduler:
                 sct.unique_id(), workload, host=self.host, accel=self.accel,
                 evaluate=self._make_evaluator(sct, workload),
                 params=self.tuner_params, kb=self.kb, sct=sct)
-            self.balancer.reset_search()
-            self.balancer.lbt = 0.0
+            self.balancer.reset()
             return result.profile, "built"
         # Adjust workload distribution (adaptive binary search) from the
         # last observed per-class makespans (scheduler-owned state: the
@@ -1233,14 +1236,14 @@ class Scheduler:
                 slots.append(ExecutionSlot(device=f"{d.name}/q{o}",
                                            device_type=d.kind,
                                            wgs=dict(profile.config.wgs),
-                                           jax_device=d.jax_device))
+                                           info=d))
         host = self.host.device
         if self.health.usable(host.name):
             for i in range(self.host.parallelism):
                 slots.append(ExecutionSlot(
                     device=f"{host.name}/f{i}", device_type="cpu",
                     wgs=dict(profile.config.wgs),
-                    jax_device=host.jax_device))
+                    info=host))
         if not slots:
             raise ExecutionError(
                 "all devices quarantined: no execution slots available "

@@ -3,7 +3,7 @@
 Gray-scale volume -> {black, gray, white} by two thresholds.  The
 elementary partitioning unit is one (D2 x D3) plane, and planes lie on
 axis 0, as in the paper workload (``benchmarks/paper_suite.py``).  The
-grid walks the planes, and each plane in bands of ``block_rows`` rows,
+grid walks the planes, and each plane in bands of ``BLOCK_ROWS`` rows,
 so a block is (1, rows, D3) with the plane's rows and columns last.
 """
 from __future__ import annotations
@@ -14,6 +14,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+#: rows per band: a 256 x 1024 f32 band is 1 MiB, well inside VMEM
+BLOCK_ROWS = 256
+
 
 def _seg_kernel(vol_ref, o_ref, *, lo: float, hi: float):
     v = vol_ref[...]
@@ -22,10 +25,10 @@ def _seg_kernel(vol_ref, o_ref, *, lo: float, hi: float):
 
 
 def segmentation(vol: jax.Array, *, lo: float = 85.0, hi: float = 170.0,
-                 block_rows: int = 256, interpret: bool = False) -> jax.Array:
+                 interpret: bool = False) -> jax.Array:
     """vol (planes, D2, D3) f32 -> segmented volume (plane-partitioned)."""
     D1, D2, D3 = vol.shape
-    br = min(block_rows, D2)
+    br = min(BLOCK_ROWS, D2)
     kernel = functools.partial(_seg_kernel, lo=lo, hi=hi)
     block = pl.BlockSpec((1, br, D3), lambda p, i: (p, i, 0))
     return pl.pallas_call(

@@ -29,6 +29,13 @@ TINY_KERNELS = {"saxpy": 1000, "filter_pipeline": 64, "segmentation": 1,
                 "nbody": 64}
 
 
+def accel_platform(devices):
+    """The accelerator class over CPU devices: classed ``accel`` so the
+    scheduler keeps them out of the host class, which is kind ``cpu``."""
+    return AcceleratorPlatform([DeviceInfo(f"accel{d.id}", "accel",
+                                           jax_device=d) for d in devices])
+
+
 def _child(code: str, *, devices: int = 1, env=None, cwd=ROOT,
            timeout: float = 300) -> subprocess.CompletedProcess:
     full = {**os.environ, "JAX_PLATFORMS": "cpu",
@@ -51,9 +58,8 @@ def test_from_jax_records_devices_and_leaves_peaks_unknown():
     accel = AcceleratorPlatform.from_jax([dev, dev])
     host = HostPlatform.from_jax()
     for info in accel.devices:
-        assert info.jax_device is dev and info.kind == "accel"
-        assert (info.platform, info.device_kind) == (dev.platform,
-                                                     dev.device_kind)
+        assert info.jax_device is dev and info.kind == dev.platform
+        assert info.name == f"{dev.platform}{dev.id}"
         assert info.peak_flops is None and info.hbm_bw is None
     assert accel.calibrate() == [0.5, 0.5]
     assert host.device.kind == "cpu"
@@ -92,7 +98,8 @@ def test_filter_pipeline_split_equals_whole_image():
 
 @pytest.fixture(scope="module")
 def smoke_session():
-    session, telemetry = chip_smoke.build_session(jax.devices()[:1])
+    session, telemetry = chip_smoke.build_session(
+        accel_platform(jax.devices()[:1]))
     yield session, telemetry
     session.shutdown()
 
@@ -151,9 +158,13 @@ def four_devices():
         import json, jax
         import chip_smoke
         from benchmarks.paper_suite import make_inputs, saxpy_sct
-        from repro.core import JobGraph
+        from repro.core import AcceleratorPlatform, DeviceInfo, JobGraph
+        def accel_platform(devices):
+            return AcceleratorPlatform([DeviceInfo(f"accel{d.id}", "accel",
+                                                   jax_device=d)
+                                        for d in devices])
         devs = jax.devices()
-        session, tel = chip_smoke.build_session(devs)
+        session, tel = chip_smoke.build_session(accel_platform(devs))
         with session:
             graph = JobGraph()
             graph.add(saxpy_sct())
@@ -163,7 +174,7 @@ def four_devices():
                           "cpu": str(jax.devices("cpu")[0]),
                           "spans": spans}))
         print(json.dumps(chip_smoke.four_chip_phase(
-            "filter_pipeline", 64, devs[:4])))
+            "filter_pipeline", 64, accel_platform(devs[:4]))))
     """, devices=4)
     assert proc.returncode == 0, proc.stderr[-3000:]
     lines = proc.stdout.strip().splitlines()

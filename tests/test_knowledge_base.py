@@ -96,6 +96,16 @@ class TestDerivation:
         assert got.share_a == 0.25            # nearest neighbour
         assert got.config.fission_level == "L3"
 
+    def test_far_workload_gets_the_profiles_mean_share(self):
+        # a new SCT whose workload lies far from every profile of the same
+        # dimensionality: the Gaussians vanish there, and the derived share
+        # is the profiles' mean, not a share near 0 that none of them has
+        kb = KnowledgeBase()
+        kb.store(prof("filter", (4096, 4096), 0.8))
+        kb.store(prof("fft", (256, 65536), 0.75))
+        got = kb.derive("nbody", Workload((8192, 4)))
+        assert 0.75 <= got.share_a <= 0.8
+
     def test_monotone_interpolation_tracks_size(self):
         """Table 5-style: derived share follows workload size trend."""
         kb = KnowledgeBase()

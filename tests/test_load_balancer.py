@@ -62,3 +62,37 @@ class TestCorrector:
             deltas.append(new.a - cur.a)
             cur = new
         assert deltas[-1] > deltas[0]
+
+
+class TestSchedulerDetector:
+    @staticmethod
+    def _scheduler():
+        from repro.core import (AcceleratorPlatform, DeviceInfo, HostPlatform,
+                                KnowledgeBase, Scheduler)
+        from repro.core.simulator import SimDevice, SimulatedExecutor
+        sim = SimulatedExecutor([SimDevice("gpu0", "gpu", flops=1e12),
+                                 SimDevice("cpu0", "cpu", flops=1e11)])
+        # max_dev > 1: every run counts as unbalanced
+        return Scheduler(host=HostPlatform(DeviceInfo("cpu0", "cpu")),
+                         accel=AcceleratorPlatform([DeviceInfo("gpu0", "gpu")]),
+                         executor=sim, kb=KnowledgeBase(),
+                         balancer=LoadBalancer(max_dev=1.5))
+
+    def test_switching_sct_restarts_the_detector(self):
+        """Another SCT's unbalanced runs must not trigger an adjustment of
+        the SCT that follows it."""
+        import numpy as np
+        from repro.core import kernel, vector
+        add = kernel(lambda x: x + 1, name="add_one",
+                     inputs=[vector("x")], outputs=[vector("z")])
+        neg = kernel(lambda x: -x, name="negate",
+                     inputs=[vector("x")], outputs=[vector("z")])
+        x = {"x": np.arange(256, dtype=np.float32)}
+        sched = self._scheduler()
+        for _ in range(3):
+            sched.run(add, dict(x))
+        assert sched.balancer.lbt >= sched.balancer.trigger
+        assert sched.run(neg, dict(x)).action == "derived"
+        assert sched.run(neg, dict(x)).action == "reused"
+        assert sched.run(neg, dict(x)).action == "reused"
+        assert sched.run(neg, dict(x)).action == "adjusted"
