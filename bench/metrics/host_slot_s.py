@@ -1,0 +1,8 @@
+"""Mean ``ExecutionStats.time_b`` per request of the window: the host class's makespan,
+on the host clock of the executor."""
+import numpy as np
+
+
+def read(ctx):
+    vals = [r.stats.time_b for r in ctx.requests if r.ok]
+    return float(np.mean(vals)) if vals else None
