@@ -6,8 +6,9 @@ then checks:
 
   * ``Session.export_trace`` writes a well-formed Chrome trace
     (``validate_chrome_trace``: required keys, matched B/E pairs);
-  * the trace contains the plan, per-slot compute, retry (attempt > 0)
-    and merge spans the span model promises;
+  * the trace contains the submit, plan, per-slot compute and
+    write-back, retry (attempt > 0) and merge spans the span model
+    promises;
   * ``Session.metrics()`` retry / plan-cache counters match the
     ``ExecutionStats`` the same runs returned;
   * a fault event and a repartition event were logged;
@@ -42,7 +43,8 @@ POLICY = FaultPolicy(watchdog_multiple=1e6)
 
 # required by the span model (docs/observability.md); "attempt" spans with
 # attempt >= 1 are the retry spans
-REQUIRED_SPANS = {"run", "plan", "dispatch", "attempt", "slot", "merge"}
+REQUIRED_SPANS = {"submit", "run", "plan", "dispatch", "attempt", "slot",
+                  "compute", "writeback", "merge"}
 
 
 def chain_kernels():

@@ -63,6 +63,25 @@ are merged into host buffers.  With telemetry on, each ``slot`` span
 notes ``bound`` (the slot's device) and ``placed`` (the devices that hold
 its outputs).
 
+What crosses between host and accelerator is counted on every run, as
+bytes handed over (not as DMA transfers, which JAX performs and may
+split, pad or skip): ``h2d_bytes`` sums the host-resident values (NumPy
+arrays and NumPy scalars) handed to accelerator-class slots, and
+``d2h_bytes`` the accelerator-class outputs (``jax.Array``) read into
+host memory by a slot's direct write or by the merge's copies (values a
+user merge function combines, COPY outputs handed back as they are and
+what a resident chain keeps on the slots are not counted).  The counts
+are taken where the slot receives its values and where its outputs are
+written back, so a copy made anywhere else (an explicit ``device_put``
+before the slot, a read-back by the caller) is not in them.
+
+Each ``slot`` span (``cls="a"`` for accelerator-class slots, ``"b"`` for
+the host class) has two children, also timed on every run: ``compute``
+(segment environment, ``sct.apply`` and ``block_until_ready``, so the
+implicit upload of host inputs) and ``writeback`` (the direct write into
+host buffers, so the read-back).  The run's ``compute_a`` /
+``writeback_a`` are those of the accelerator slot with the longest time.
+
 Failure semantics
 -----------------
 Execution is tracked per *segment* — a contiguous domain-unit range bound
@@ -89,7 +108,8 @@ import contextlib
 import dataclasses
 import threading
 import time
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple, Union
+from typing import (Any, Callable, Dict, Iterable, List, Optional, Sequence,
+                    Tuple, Union)
 
 import jax
 import numpy as np
@@ -131,6 +151,10 @@ class ExecResult:
     direct_bytes: int
     resident: Optional["ResidentPartition"]
     n_a: int                                # accelerator-class slot count
+    h2d_bytes: int = 0                      # host values to class-a slots
+    d2h_bytes: int = 0                      # class-a outputs read to host
+    compute_a: float = 0.0                  # slowest class-a slot: compute
+    writeback_a: float = 0.0                # ... and its write-back
 
 
 @dataclasses.dataclass
@@ -138,6 +162,10 @@ class _SlotResult:
     outputs: Dict[str, Any]
     seconds: float
     written: frozenset = frozenset()    # outputs direct-written to buffers
+    h2d_bytes: int = 0                  # host values handed to the slot
+    d2h_bytes: int = 0                  # outputs direct-written to host
+    compute_s: float = 0.0              # segment env, apply, ready
+    writeback_s: float = 0.0            # direct write to host buffers
 
 
 @dataclasses.dataclass
@@ -352,18 +380,14 @@ class ThreadedExecutor:
             q.shutdown(wait=False, cancel_futures=True)
 
     def _acquire_pool(self, n: int) -> cf.ThreadPoolExecutor:
-        with self.telemetry.tracer.span("pool", workers=n) as sp:
-            if self._pool is not None and self._pool_size < n:
-                self._retire_pool()
-            if self._pool is None:
-                self._pool = cf.ThreadPoolExecutor(max_workers=n)
-                self._pool_size = n
-                self.pools_created += 1
-                self.telemetry.metrics.counter("pools_created_total").inc()
-                sp.note(created=True)
-            else:
-                self.pool_reuses += 1
-                self.telemetry.metrics.counter("pool_reuses_total").inc()
+        if self._pool is not None and self._pool_size < n:
+            self._retire_pool()
+        if self._pool is None:
+            self._pool = cf.ThreadPoolExecutor(max_workers=n)
+            self._pool_size = n
+            self.pools_created += 1
+        else:
+            self.pool_reuses += 1
         return self._pool
 
     def _acquire_queues(self, devices: Sequence[str]
@@ -373,23 +397,19 @@ class ThreadedExecutor:
         segments bound to the same device serialise in its queue;
         segments on disjoint devices genuinely overlap — including
         segments of *different* graph nodes."""
-        with self.telemetry.tracer.span("pool", workers=len(devices)) as sp:
-            created = False
-            with self._queue_lock:
-                for d in devices:
-                    if d not in self._queues:
-                        self._queues[d] = cf.ThreadPoolExecutor(
-                            max_workers=1,
-                            thread_name_prefix=f"wq-{d.replace('/', '-')}")
-                        created = True
-                qmap = {d: self._queues[d] for d in devices}
-            if created:
-                self.pools_created += 1
-                self.telemetry.metrics.counter("pools_created_total").inc()
-                sp.note(created=True)
-            else:
-                self.pool_reuses += 1
-                self.telemetry.metrics.counter("pool_reuses_total").inc()
+        created = False
+        with self._queue_lock:
+            for d in devices:
+                if d not in self._queues:
+                    self._queues[d] = cf.ThreadPoolExecutor(
+                        max_workers=1,
+                        thread_name_prefix=f"wq-{d.replace('/', '-')}")
+                    created = True
+            qmap = {d: self._queues[d] for d in devices}
+        if created:
+            self.pools_created += 1
+        else:
+            self.pool_reuses += 1
         return qmap
 
     # -- Scheduler interface -------------------------------------------------
@@ -417,18 +437,20 @@ class ThreadedExecutor:
     def execute_result(self, sct: SCT, part: ConcretePartitioning,
                        arrays: Dict[str, Any], profile: Profile, *,
                        resident: Optional[ResidentPartition] = None,
-                       keep_resident: bool = False) -> ExecResult:
+                       keep_resident: bool = False,
+                       request: Optional[str] = None) -> ExecResult:
         """Execute one partitioned run and return a per-call result.
 
         Thread-safe: concurrent graph nodes share the per-device work
         queues and the buffer pool (leased per call), and nothing about
-        this call is observed through shared mutable state."""
+        this call is observed through shared mutable state.  ``request``
+        (the graph request id) labels every span of the call."""
         with self.telemetry.tracer.span(
-                "dispatch", sct=sct.unique_id(), slots=len(part.slots),
-                keep_resident=keep_resident) as sp:
+                "dispatch", request=request, sct=sct.unique_id(),
+                slots=len(part.slots), keep_resident=keep_resident) as sp:
             res = self._execute(
                 sct, part, arrays, profile, resident=resident,
-                keep_resident=keep_resident)
+                keep_resident=keep_resident, request=request)
             sp.note(retries=res.retries,
                     merge_bytes=res.merge_bytes,
                     resident=res.resident is not None)
@@ -437,12 +459,14 @@ class ThreadedExecutor:
     def _execute(self, sct: SCT, part: ConcretePartitioning,
                  arrays: Dict[str, Any], profile: Profile, *,
                  resident: Optional[ResidentPartition] = None,
-                 keep_resident: bool = False) -> ExecResult:
+                 keep_resident: bool = False,
+                 request: Optional[str] = None) -> ExecResult:
         leases: List[np.ndarray] = []   # buffers leased to this call
         try:
             return self._execute_leased(sct, part, arrays, profile, leases,
                                         resident=resident,
-                                        keep_resident=keep_resident)
+                                        keep_resident=keep_resident,
+                                        request=request)
         finally:
             # end of the run releases its buffer leases: the *next* run may
             # overwrite the returned arrays (the documented aliasing
@@ -456,7 +480,8 @@ class ThreadedExecutor:
                         arrays: Dict[str, Any], profile: Profile,
                         leases: List[np.ndarray], *,
                         resident: Optional[ResidentPartition] = None,
-                        keep_resident: bool = False) -> ExecResult:
+                        keep_resident: bool = False,
+                        request: Optional[str] = None) -> ExecResult:
         t_run0 = time.perf_counter()
         pool_sec = [0.0]                # mutable: charged by _run_attempt
         merge_bytes = 0
@@ -486,17 +511,19 @@ class ThreadedExecutor:
         dead: set = set()
         done: List[Tuple[_Segment, _SlotResult]] = []
         per_slot_seconds = [0.0] * len(part.slots)
+        per_slot_phases = [[0.0, 0.0] for _ in part.slots]
 
         tel = self.telemetry
         attempts_seconds = 0.0
         pending = segments
         for attempt in range(self.policy.max_attempts):
             t_a0 = time.perf_counter()
-            with tel.tracer.span("attempt", attempt=attempt,
+            with tel.tracer.span("attempt", request=request,
+                                 attempt=attempt,
                                  segments=len(pending)) as att_span:
                 outcomes = self._run_attempt(sct, part, arrays, pending,
                                              deadline, attempt, resident,
-                                             targets, pool_sec)
+                                             targets, pool_sec, request)
                 attempts_seconds += time.perf_counter() - t_a0
                 failed: List[_Segment] = []
                 for seg, res in zip(pending, outcomes):
@@ -513,6 +540,8 @@ class ThreadedExecutor:
                             attempt=res.attempt, slot=res.slot)
                     else:
                         done.append((seg, res))
+                        per_slot_phases[seg.slot][0] += res.compute_s
+                        per_slot_phases[seg.slot][1] += res.writeback_s
                 att_span.note(faults=len(failed))
             lost = [s for s in failed if s.units > 0]
             if not lost:
@@ -556,17 +585,20 @@ class ThreadedExecutor:
         t_m0 = time.perf_counter()
         resident_out: Optional[ResidentPartition] = None
         direct_bytes = 0
+        d2h_bytes = sum(res.d2h_bytes for _, res in done)
         if keep_resident and clean:
-            with tel.tracer.span("resident-handoff", segments=len(done)):
+            with tel.tracer.span("resident-handoff", request=request,
+                                 segments=len(done)):
                 resident_out = self._make_resident(
                     sct, part, done, resident, inherited_extras)
             outputs: Dict[str, Any] = {}
         else:
-            with tel.tracer.span("merge") as merge_span:
-                outputs, copied, direct_bytes = self._merge(
+            with tel.tracer.span("merge", request=request) as merge_span:
+                outputs, copied, direct_bytes, read_back = self._merge(
                     sct, part, done, targets, leases)
                 merge_span.note(merge_bytes=copied)
             merge_bytes += copied
+            d2h_bytes += read_back
             if inherited_extras and keep_resident:
                 # chain fallback: surface carried values with the merge
                 outputs = {**inherited_extras, **outputs}
@@ -581,31 +613,43 @@ class ThreadedExecutor:
             "merge": merge_seconds,
             "dispatch": max(total - attempts_seconds - merge_seconds, 0.0),
         }
+        n_a = sum(1 for s in part.slots if s.device_type != "cpu")
+        # the phases of the accelerator slot that sets time_a
+        compute_a, writeback_a = (
+            per_slot_phases[max(range(n_a), key=times.__getitem__)]
+            if n_a else (0.0, 0.0))
         return ExecResult(
             outputs=outputs, times=times, failures=records, retries=retries,
             timing=timing, merge_bytes=merge_bytes,
-            direct_bytes=direct_bytes, resident=resident_out,
-            n_a=sum(1 for s in part.slots if s.device_type != "cpu"))
+            direct_bytes=direct_bytes, resident=resident_out, n_a=n_a,
+            h2d_bytes=sum(res.h2d_bytes for _, res in done),
+            d2h_bytes=d2h_bytes, compute_a=compute_a,
+            writeback_a=writeback_a)
 
     def _run_attempt(self, sct: SCT, part: ConcretePartitioning,
                      arrays: Dict[str, Any], segments: Sequence[_Segment],
                      deadline: Optional[float], attempt: int,
                      resident: Optional[ResidentPartition] = None,
                      targets: Optional[Dict[str, _OutputTarget]] = None,
-                     pool_sec: Optional[List[float]] = None
+                     pool_sec: Optional[List[float]] = None,
+                     request: Optional[str] = None
                      ) -> List[Union[_SlotResult, FaultRecord]]:
         """Run one round of segments concurrently, containing all faults."""
         targets = targets or {}
         pool_sec = pool_sec if pool_sec is not None else [0.0]
-        traced = self.telemetry.tracer.enabled
+        tracer = self.telemetry.tracer
+        traced = tracer.enabled
         produced = _produced_names(sct) if traced else []
 
         def work(seg: _Segment) -> Union[_SlotResult, FaultRecord]:
             slot = part.slots[seg.slot]
+            accel = slot.device_type != "cpu"
+            cls = "a" if accel else "b"
             t0 = time.perf_counter()
-            with self.telemetry.tracer.span(
-                    "slot", device=slot.device, units=seg.units,
-                    offset=seg.start, attempt=attempt) as sp:
+            with tracer.span(
+                    "slot", request=request, cls=cls, device=slot.device,
+                    units=seg.units, offset=seg.start,
+                    attempt=attempt) as sp:
                 try:
                     if self.injector is not None:
                         kind = self.injector.decide(slot.device)
@@ -614,20 +658,30 @@ class ThreadedExecutor:
                                 f"injected crash on {slot.device}")
                         if kind == "stall":
                             time.sleep(self.injector.stall_seconds)
-                    env = self._segment_env(part, arrays, seg, resident)
                     dev = slot.info.jax_device if slot.info else None
-                    with (jax.default_device(dev) if dev is not None
-                          else contextlib.nullcontext()):
-                        out_env = sct.apply(env)
-                        for v in out_env.values():
-                            if hasattr(v, "block_until_ready"):
-                                v.block_until_ready()
+                    t_c = time.perf_counter()
+                    with tracer.span("compute", request=request, cls=cls):
+                        env = self._segment_env(part, arrays, seg, resident)
+                        with (jax.default_device(dev) if dev is not None
+                              else contextlib.nullcontext()):
+                            out_env = sct.apply(env)
+                            for v in out_env.values():
+                                if hasattr(v, "block_until_ready"):
+                                    v.block_until_ready()
+                    t_c1 = time.perf_counter()
                     if traced:
                         sp.note(bound=None if dev is None else str(dev),
                                 placed=_placement(out_env, produced))
-                    written = self._direct_write(out_env, seg, targets)
-                    return _SlotResult(out_env, time.perf_counter() - t0,
-                                       written)
+                    t_w = time.perf_counter()
+                    with tracer.span("writeback", request=request, cls=cls):
+                        written = self._direct_write(out_env, seg, targets)
+                    t1 = time.perf_counter()
+                    return _SlotResult(
+                        out_env, t1 - t0, written,
+                        h2d_bytes=_host_bytes(env.values()) if accel else 0,
+                        d2h_bytes=_device_bytes(out_env[n] for n in written)
+                        if accel else 0,
+                        compute_s=t_c1 - t_c, writeback_s=t1 - t_w)
                 except Exception as e:   # containment: never crosses the slot
                     sp.note(fault=type(e).__name__)
                     return FaultRecord(
@@ -845,9 +899,10 @@ class ThreadedExecutor:
                done: Sequence[Tuple[_Segment, _SlotResult]],
                targets: Optional[Dict[str, _OutputTarget]] = None,
                leases: Optional[List[np.ndarray]] = None
-               ) -> Tuple[Dict[str, Any], int, int]:
-        """Merge per-segment outputs; returns
-        (outputs, bytes copied, bytes direct-written).
+               ) -> Tuple[Dict[str, Any], int, int, int]:
+        """Merge per-segment outputs; returns (outputs, bytes copied,
+        bytes direct-written, bytes of accelerator-class outputs the
+        copies read into host memory).
 
         Precedence per output name (documented contract):
           1. a user-supplied merge function (``self.merges``) — honoured
@@ -862,6 +917,9 @@ class ThreadedExecutor:
         merged: Dict[str, Any] = {}
         bytes_copied = 0
         direct_bytes = 0
+        read_back = 0
+        accel = {j for j, s in enumerate(part.slots)
+                 if s.device_type != "cpu"}
         sid = sct.unique_id()
         for name in _produced_names(sct):
             pieces = [(seg, res) for seg, res in done if name in res.outputs]
@@ -877,6 +935,9 @@ class ThreadedExecutor:
                 merged[name] = parts[0]
                 continue
             axis, _ = ae
+            read_back += _device_bytes(
+                p for (seg, res), p in zip(pieces, parts)
+                if seg.slot in accel and name not in res.written)
             if not self.inplace_merge:
                 merged[name] = np.concatenate(
                     [p if isinstance(p, np.ndarray) else np.asarray(p)
@@ -889,7 +950,7 @@ class ThreadedExecutor:
             bytes_copied += copied
             direct_bytes += direct
             self._out_shapes[(sid, name)] = (tuple(out.shape), out.dtype)
-        return merged, bytes_copied, direct_bytes
+        return merged, bytes_copied, direct_bytes, read_back
 
     def _assemble(self, name: str, axis: int,
                   pieces: Sequence[Tuple[_Segment, _SlotResult]],
@@ -1003,6 +1064,17 @@ def _placement(out_env: Dict[str, Any], names: Sequence[str]) -> List[str]:
         elif v is not None:
             where.add("host")
     return sorted(where)
+
+
+def _host_bytes(values: Iterable[Any]) -> int:
+    """Bytes of the host-resident values (NumPy arrays and scalars)."""
+    return sum(v.nbytes for v in values
+               if isinstance(v, (np.ndarray, np.generic)))
+
+
+def _device_bytes(values: Iterable[Any]) -> int:
+    """Bytes of the ``jax.Array`` values."""
+    return sum(v.nbytes for v in values if isinstance(v, jax.Array))
 
 
 def _produced_names(sct: SCT) -> List[str]:
@@ -1138,17 +1210,20 @@ class Session:
 
         Blocks while ``max_inflight`` earlier submissions are still
         unsettled (backpressure); per-node ``retries`` / ``deadline``
-        semantics match :meth:`run`."""
+        semantics match :meth:`run`.  A ``submit`` span covers the wait
+        and the scheduler's admission, and notes the request id."""
         if self._closed:
             raise RuntimeError("session is shut down")
-        self._inflight.acquire()
-        try:
-            handle = self.scheduler.submit(
-                graph, arrays, deadline=deadline, retries=retries,
-                retry_backoff=retry_backoff)
-        except BaseException:
-            self._inflight.release()
-            raise
+        with self.telemetry.tracer.span("submit") as sp:
+            self._inflight.acquire()
+            try:
+                handle = self.scheduler.submit(
+                    graph, arrays, deadline=deadline, retries=retries,
+                    retry_backoff=retry_backoff)
+            except BaseException:
+                self._inflight.release()
+                raise
+            sp.note(request=handle.request_id)
         handle.add_done_callback(lambda _h: self._inflight.release())
         return handle
 

@@ -245,6 +245,12 @@ class GraphHandle:
     aggregate :class:`~repro.core.faults.ExecutionError` when any node
     failed (independent branches still ran to completion and their runs
     stay accessible via :attr:`runs`).
+
+    Each run's ``stats.queue_seconds`` is the seconds the graph waited
+    between ``Scheduler.submit`` queuing it and its admission (the start
+    of its driver); for a request fused with others, from joining its
+    fusion batch to the batch's start, so the fusion window is in it.
+    It stays 0.0 on the virtual-clock path, which runs the graph inline.
     """
 
     def __init__(self, graph: JobGraph, request_id: str):
@@ -382,6 +388,8 @@ class GraphDriver:
         self.preplanned = preplanned
         self.plan_key = plan_key
         self.plan_epoch = plan_epoch
+        self.queued_at = 0.0            # perf_counter when it was queued
+        self.queued_s = 0.0             # admission wait, set by start()
         self._t0 = time.monotonic()
         self._epoch = time.perf_counter()
         self._lock = threading.Lock()
@@ -437,6 +445,7 @@ class GraphDriver:
         plan = (self.preplanned[self._pos[name]]
                 if self.preplanned is not None else None)
         tel = self.sched.telemetry
+        rid = self.handle.request_id
         last: Optional[ExecutionError] = None
         for k in range(self.retries + 1):
             if self.deadline is not None and \
@@ -445,10 +454,12 @@ class GraphDriver:
                     f"request deadline {self.deadline}s exceeded after "
                     f"{k} attempts", getattr(last, "records", []), k)
             try:
-                with tel.tracer.span("node", request=self.handle.request_id,
-                                     node=name, retry=k):
-                    return self.sched.run(node.sct, env, _resident=resident,
-                                          _keep_resident=keep, _plan=plan)
+                with tel.tracer.span("node", request=rid, node=name, retry=k):
+                    run = self.sched.run(node.sct, env, _resident=resident,
+                                         _keep_resident=keep, _plan=plan,
+                                         _request=rid)
+                run.stats.queue_seconds = self.queued_s
+                return run
             except ExecutionError as e:
                 last = e
                 if k == self.retries:
@@ -468,6 +479,7 @@ class GraphDriver:
     # -- threaded (concurrent) mode ------------------------------------------
     def start(self) -> None:
         """Admit the graph: schedule every dependency-free node."""
+        self.queued_s = time.perf_counter() - self.queued_at
         tel = self.sched.telemetry
         tel.events.emit("graph.admitted", request=self.handle.request_id,
                         nodes=self._n)

@@ -56,7 +56,14 @@ class ExecutionStats:
     counts bytes copied at merge time — 0 on the resident-chain path and
     whenever every partitionable output was written in place by its
     slot.  ``plan_cache_hit`` / ``resident`` flag which fast paths the
-    run took.
+    run took.  ``h2d_bytes`` / ``d2h_bytes`` count the bytes handed
+    between host memory and the accelerator-class slots, each way, and
+    ``compute_a`` / ``writeback_a`` split ``time_a`` into the two phases
+    of the accelerator slot that set it: computing its outputs (the
+    implicit upload of host inputs included) and writing them back into
+    host buffers (see :mod:`repro.core.executor`).  ``queue_seconds`` is
+    how long the request this run belongs to waited for admission in
+    the Scheduler (see :class:`~repro.core.graph.GraphHandle`).
     """
 
     times: List[float]           # per concurrent execution
@@ -73,6 +80,11 @@ class ExecutionStats:
     merge_bytes: int = 0         # bytes copied during merge (0 = zero-copy)
     plan_cache_hit: bool = False  # partitioning served from the plan cache
     resident: bool = False       # outputs left slot-resident (merge skipped)
+    h2d_bytes: int = 0           # host values handed to accelerator slots
+    d2h_bytes: int = 0           # accelerator outputs read into host memory
+    compute_a: float = 0.0       # time_a's slot: segment compute
+    writeback_a: float = 0.0     # time_a's slot: write-back to host buffers
+    queue_seconds: float = 0.0   # the request's wait for admission
 
     @property
     def ok(self) -> bool:

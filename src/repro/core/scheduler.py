@@ -146,6 +146,8 @@ class _FusionMember:
     handle: GraphHandle
     node: str
     sct: SCT
+    joined_at: float            # perf_counter when it joined the batch
+    queued_s: float = 0.0       # joined_at to the batch's start
 
 
 class _FusionBatch:
@@ -428,7 +430,8 @@ class Scheduler:
     def run(self, sct: SCT, arrays: Dict[str, Any],
             workload: Optional[Workload] = None, *,
             _resident=None, _keep_resident: bool = False,
-            _plan: Optional[NodePlan] = None) -> ScheduledRun:
+            _plan: Optional[NodePlan] = None,
+            _request: Optional[str] = None) -> ScheduledRun:
         """One scheduled execution.  Thread-safe: the decision and
         observation phases serialise on the scheduler lock; the execute
         phase runs unlocked, so independent graph nodes overlap on the
@@ -441,7 +444,8 @@ class Scheduler:
         version moved since it was recorded) falls back to ordinary
         planning.  The observation phase runs either way, so KB
         ``best_time`` refinement and lbt updates see pre-planned runs
-        too."""
+        too.  ``_request`` (internal — the graph request id) labels
+        every span of the run."""
         plan: Optional[NodePlan] = None
         if (_plan is not None and self.plan_cache.enabled
                 and _plan.health_version == self.health.version):
@@ -454,7 +458,7 @@ class Scheduler:
 
         tel = self.telemetry
         wl = str(workload.key()) if workload is not None else "preplanned"
-        with tel.tracer.span("run", sct=sct.unique_id(),
+        with tel.tracer.span("run", request=_request, sct=sct.unique_id(),
                              workload=wl) as run_span:
             if plan is None:
                 with self._lock:        # decision phase (Fig. 4)
@@ -495,7 +499,8 @@ class Scheduler:
                 outputs, stats, slots, resident_handle, node_plan = \
                     self._dispatch(
                         sct, arrays, profile, resident=_resident,
-                        keep_resident=_keep_resident, plan=plan)
+                        keep_resident=_keep_resident, plan=plan,
+                        request=_request)
             except ExecutionError as e:
                 # terminal failure: still feed the health tracker, so repeat
                 # offenders get quarantined even when no run ever completes
@@ -562,6 +567,8 @@ class Scheduler:
         if stats.resident:
             tel.metrics.counter("resident_handoffs_total").inc()
         tel.metrics.counter("merge_bytes_total").inc(stats.merge_bytes)
+        tel.metrics.counter("h2d_bytes_total").inc(stats.h2d_bytes)
+        tel.metrics.counter("d2h_bytes_total").inc(stats.d2h_bytes)
         tel.metrics.histogram("class_makespan_seconds",
                               cls="a").observe(stats.time_a)
         tel.metrics.histogram("class_makespan_seconds",
@@ -643,7 +650,8 @@ class Scheduler:
         with ``fusion_window > 0`` — identical single-node graphs
         admitted within the window coalesce into one fused run (module
         docstring).  Both settle the returned handle exactly as the
-        ordinary path does."""
+        ordinary path does.  Each run's ``stats.queue_seconds`` records
+        how long the graph waited for admission."""
         graph.validate()
         tel = self.telemetry
         virtual = bool(getattr(self.executor, "virtual_clock", False))
@@ -672,6 +680,7 @@ class Scheduler:
         if virtual:
             driver.run_virtual()
             return handle
+        driver.queued_at = time.perf_counter()
         with self._graph_lock:
             self._admission.append(driver)
             started = self._pump_locked()
@@ -763,10 +772,9 @@ class Scheduler:
                 timer.daemon = True
                 batch.timer = timer
                 timer.start()
-            batch.members.append(_FusionMember(arrays=dict(arrays),
-                                               handle=handle,
-                                               node=node.name,
-                                               sct=node.sct))
+            batch.members.append(_FusionMember(
+                arrays=dict(arrays), handle=handle, node=node.name,
+                sct=node.sct, joined_at=time.perf_counter()))
             if len(batch.members) >= self.fusion_max:
                 flush = self._close_batch_locked(batch)
         if flush is not None:
@@ -892,8 +900,8 @@ class Scheduler:
                 start = now_us()
                 try:
                     run = self._request_with_retries(
-                        m.sct, m.arrays, deadline=deadline,
-                        retries=retries, backoff=backoff)
+                        m.sct, m.arrays, m.handle.request_id,
+                        deadline=deadline, retries=retries, backoff=backoff)
                 except BaseException as e:
                     self._settle_member(m, error=e, span=(start, now_us()))
                 else:
@@ -910,8 +918,9 @@ class Scheduler:
         start = now_us()
         try:
             run = self._request_with_retries(
-                members[0].sct, fused_arrays, deadline=deadline,
-                retries=retries, backoff=backoff)
+                members[0].sct, fused_arrays,
+                "+".join(m.handle.request_id for m in members),
+                deadline=deadline, retries=retries, backoff=backoff)
         except BaseException as e:
             end = now_us()
             for m in members:
@@ -979,11 +988,14 @@ class Scheduler:
             slicers[oname] = (spec.partition_dim, units * spec.epu)
         return fused, slicers
 
-    def _request_with_retries(self, sct: SCT, arrays: Dict[str, Any], *,
+    def _request_with_retries(self, sct: SCT, arrays: Dict[str, Any],
+                              request: str, *,
                               deadline: Optional[float], retries: int,
                               backoff: float) -> ScheduledRun:
         """Per-request retry loop around :meth:`run` (fused path) —
-        same deadline-capped exponential backoff as ``GraphDriver``."""
+        same deadline-capped exponential backoff as ``GraphDriver``.
+        ``request`` labels the run's spans (the member ids of a fused
+        batch, joined by ``+``)."""
         t0 = time.monotonic()
         last: Optional[ExecutionError] = None
         for k in range(retries + 1):
@@ -992,7 +1004,7 @@ class Scheduler:
                     f"request deadline {deadline}s exceeded after "
                     f"{k} attempts", getattr(last, "records", []), k)
             try:
-                return self.run(sct, arrays)
+                return self.run(sct, arrays, _request=request)
             except ExecutionError as e:
                 last = e
                 if k == retries:
@@ -1027,7 +1039,10 @@ class Scheduler:
                             message=str(error))
             handle._finish(_wrap_node_error(name, error))
             return
-        handle.runs[name] = run
+        # members of one fused run share its stats; the wait is each one's
+        handle.runs[name] = dataclasses.replace(
+            run, stats=dataclasses.replace(run.stats,
+                                           queue_seconds=member.queued_s))
         with handle._lock:
             handle._state[name] = "done"
             handle._spans[name] = span
@@ -1145,7 +1160,8 @@ class Scheduler:
     # ------------------------------------------------------------------
     def _dispatch(self, sct: SCT, arrays: Dict[str, Any], profile: Profile,
                   *, resident=None, keep_resident: bool = False,
-                  plan: Optional[NodePlan] = None
+                  plan: Optional[NodePlan] = None,
+                  request: Optional[str] = None
                   ) -> Tuple[Dict[str, Any], ExecutionStats,
                              List[ExecutionSlot], Any, NodePlan]:
         """Plan + execute one run; returns (outputs, stats, slots,
@@ -1162,7 +1178,8 @@ class Scheduler:
         else:
             with self._plan_lock:
                 self._counts["plan_locks"] += 1
-                with self.telemetry.tracer.span("plan") as plan_span:
+                with self.telemetry.tracer.span(
+                        "plan", request=request) as plan_span:
                     shapes = {k: tuple(getattr(v, "shape", ()))
                               for k, v in arrays.items()}
                     if resident is not None:
@@ -1185,13 +1202,18 @@ class Scheduler:
         if getattr(self.executor, "supports_residency", False):
             kwargs = {"resident": resident, "keep_resident": keep_resident}
         execute_result = getattr(self.executor, "execute_result", None)
+        h2d_bytes = d2h_bytes = 0
+        compute_a = writeback_a = 0.0
         if execute_result is not None:
             # per-call result object: safe under concurrent graph nodes
-            res = execute_result(sct, part, arrays, profile, **kwargs)
+            res = execute_result(sct, part, arrays, profile,
+                                 request=request, **kwargs)
             outputs, times = res.outputs, res.times
             failures, retries = res.failures, res.retries
             timing = dict(res.timing or {})
             merge_bytes = res.merge_bytes
+            h2d_bytes, d2h_bytes = res.h2d_bytes, res.d2h_bytes
+            compute_a, writeback_a = res.compute_a, res.writeback_a
             resident_out = res.resident
         else:
             # legacy duck-typed executor: observe through last_* fields
@@ -1215,7 +1237,9 @@ class Scheduler:
             merge_seconds=float(timing.get("merge", 0.0)),
             merge_bytes=merge_bytes,
             plan_cache_hit=cache_hit,
-            resident=resident_out is not None)
+            resident=resident_out is not None,
+            h2d_bytes=h2d_bytes, d2h_bytes=d2h_bytes,
+            compute_a=compute_a, writeback_a=writeback_a)
         return outputs, stats, list(slots), resident_out, node_plan
 
     def _usable_accel_devices(self):
@@ -1320,6 +1344,9 @@ class _FusedDriver:
         self.handle = batch.members[0].handle   # drain()'s wait probe
 
     def start(self) -> None:
+        now = time.perf_counter()
+        for m in self.batch.members:
+            m.queued_s = now - m.joined_at
         self.sched._graph_pool().submit(self._main)
 
     def _main(self) -> None:

@@ -149,7 +149,8 @@ class SimulatedExecutor:
 
     # -- Scheduler interface -------------------------------------------------
     def execute(self, sct: SCT, part: ConcretePartitioning,
-                arrays: Dict[str, Any], profile: Profile
+                arrays: Dict[str, Any], profile: Profile, *,
+                request: Optional[str] = None
                 ) -> Tuple[Dict[str, Any], List[float]]:
         workload = _workload_of(part)
         cost = self.cost_override or CostModel.of(sct, workload)
@@ -192,7 +193,7 @@ class SimulatedExecutor:
                         times[j] += deadline
                         round_max = max(round_max, deadline)
                         self._observe_slot(slot, units, deadline, attempt,
-                                           round_us, fault=rec)
+                                           round_us, request, fault=rec)
                         continue
                 if kind == "crash":
                     # the slot dies halfway through its simulated run
@@ -207,11 +208,12 @@ class SimulatedExecutor:
                     times[j] += t * 0.5
                     round_max = max(round_max, t * 0.5)
                     self._observe_slot(slot, units, t * 0.5, attempt,
-                                       round_us, fault=rec)
+                                       round_us, request, fault=rec)
                     continue
                 times[j] += t
                 round_max = max(round_max, t)
-                self._observe_slot(slot, units, t, attempt, round_us)
+                self._observe_slot(slot, units, t, attempt, round_us,
+                                   request)
             self._vclock_us = round_us + round_max * 1e6
             lost_units = sum(u for u in failed.values() if u > 0)
             if not lost_units:
@@ -246,13 +248,15 @@ class SimulatedExecutor:
         return outputs, times
 
     def execute_result(self, sct: SCT, part: ConcretePartitioning,
-                       arrays: Dict[str, Any], profile: Profile):
+                       arrays: Dict[str, Any], profile: Profile, *,
+                       request: Optional[str] = None):
         """Per-call result (``ExecResult``) matching the threaded
         executor's concurrent interface.  The simulator itself is
         single-threaded (graph execution is sequential in virtual time),
         so packaging from the ``last_*`` fields is race-free."""
         from repro.core.executor import ExecResult
-        outputs, times = self.execute(sct, part, arrays, profile)
+        outputs, times = self.execute(sct, part, arrays, profile,
+                                      request=request)
         return ExecResult(
             outputs=outputs, times=times,
             failures=list(self.last_failures), retries=self.last_retries,
@@ -260,7 +264,7 @@ class SimulatedExecutor:
             resident=None, n_a=self._last_n_a)
 
     def _observe_slot(self, slot, units: int, seconds: float, attempt: int,
-                      round_us: float,
+                      round_us: float, request: Optional[str] = None,
                       fault: Optional[FaultRecord] = None) -> None:
         """Telemetry for one simulated slot execution.
 
@@ -272,7 +276,8 @@ class SimulatedExecutor:
         base = slot.device.split("/")[0]
         tid = list(self.devices).index(base) if base in self.devices else 0
         tel.tracer.record("slot", round_us, seconds * 1e6, tid=tid,
-                          device=slot.device, units=units, attempt=attempt,
+                          request=request, device=slot.device, units=units,
+                          attempt=attempt,
                           **({"fault": fault.kind} if fault else {}))
         # per-device busy seconds are accounted once, by the Scheduler,
         # from stats.times — identical for both executors

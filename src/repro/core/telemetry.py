@@ -17,6 +17,12 @@ three standard observability primitives, dependency-free:
     buffer exports as Chrome/Perfetto ``trace.json``
     (``chrome://tracing`` / https://ui.perfetto.dev).
 
+:class:`ProfilerTracer`
+    The same spans written into the ``jax.profiler`` trace instead:
+    each span is a ``TraceAnnotation`` named ``repro.<name>`` on the
+    thread that runs it, so it lands on the trace's host plane on the
+    same clock as the device's operations (``Telemetry(profiler=True)``).
+
 :class:`MetricsRegistry`
     Counters, gauges and histograms with optional labels, a
     Prometheus-style text dump (:meth:`~MetricsRegistry.to_prometheus`)
@@ -48,6 +54,9 @@ enforces a per-span cost bound with a microbenchmark.
 Determinism: all timestamps come from the injectable ``clock``
 (default ``time.perf_counter``); with a counting clock and the seeded
 simulator the full event stream is reproducible bit-for-bit.
+
+The module needs only the standard library; the profiler sink imports
+``jax`` when a :class:`ProfilerTracer` is built.
 """
 from __future__ import annotations
 
@@ -216,6 +225,63 @@ class Tracer:
                                "tid": key[1],
                                "args": {"unterminated": True}})
         return {"traceEvents": events, "displayTimeUnit": "ms"}
+
+
+#: prefix of every span name in the ``jax.profiler`` trace
+PROFILER_PREFIX = "repro."
+
+
+class _ProfilerSpan:
+    """One live span of a :class:`ProfilerTracer`: a ``TraceAnnotation``
+    entered and exited on the caller's thread."""
+
+    __slots__ = ("_annotation",)
+
+    def __init__(self, annotation):
+        self._annotation = annotation
+
+    def note(self, **attrs) -> None:
+        """Attach attributes to the profiler event (kept only while a
+        ``jax.profiler`` trace is being recorded)."""
+        self._annotation.set_metadata(**attrs)
+
+    def __enter__(self) -> "_ProfilerSpan":
+        self._annotation.__enter__()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        if exc_type is not None:
+            self._annotation.set_metadata(error=exc_type.__name__)
+        self._annotation.__exit__(exc_type, exc, tb)
+        return False
+
+
+class ProfilerTracer(Tracer):
+    """Tracer whose spans are ``jax.profiler`` annotations.
+
+    ``span(name, **attrs)`` enters ``TraceAnnotation("repro." + name,
+    **attrs)``; ``note`` adds metadata to it.  The events exist only
+    while a profiler trace is recording (``jax.profiler.start_trace``)
+    and are written out with it, on the host plane beside the device's
+    operations; outside a trace a span costs about a microsecond.  The
+    in-memory Chrome buffer is not filled by spans or instants: the
+    profiler is the store.  :meth:`Tracer.record` (virtual-time spans
+    of the simulator) still goes to the Chrome buffer.
+    """
+
+    def __init__(self, *, clock: Callable[[], float] = time.perf_counter,
+                 capacity: int = 100_000):
+        super().__init__(clock=clock, capacity=capacity)
+        from jax.profiler import TraceAnnotation
+        self._annotation = TraceAnnotation
+
+    def span(self, name: str, **attrs) -> _ProfilerSpan:  # type: ignore[override]
+        return _ProfilerSpan(self._annotation(PROFILER_PREFIX + name,
+                                              **attrs))
+
+    def instant(self, name: str, **attrs) -> None:
+        with self._annotation(PROFILER_PREFIX + name, **attrs):
+            pass
 
 
 class _NullTracer(Tracer):
@@ -531,17 +597,20 @@ class Telemetry:
     ``Telemetry()`` is the enabled collector; :data:`NULL_TELEMETRY`
     (also ``Telemetry.disabled()``) is the shared off-by-default
     instance whose operations are no-ops (except warning-level event
-    bridging, see :class:`EventLog`).
+    bridging, see :class:`EventLog`).  ``profiler=True`` writes the
+    spans into the ``jax.profiler`` trace (:class:`ProfilerTracer`)
+    instead of the Chrome buffer.
     """
 
-    def __init__(self, *, enabled: bool = True,
+    def __init__(self, *, enabled: bool = True, profiler: bool = False,
                  clock: Callable[[], float] = time.perf_counter,
                  span_capacity: int = 100_000, event_capacity: int = 1024,
                  sink: Optional[Callable[[Event], None]] = None,
                  log_bridge: bool = True):
         self.enabled = enabled
         if enabled:
-            self.tracer: Tracer = Tracer(clock=clock,
+            tracer = ProfilerTracer if profiler else Tracer
+            self.tracer: Tracer = tracer(clock=clock,
                                          capacity=span_capacity)
             self.metrics: MetricsRegistry = MetricsRegistry()
             self.events: EventLog = EventLog(capacity=event_capacity,

@@ -10,14 +10,17 @@ import shutil
 import subprocess
 import sys
 import textwrap
+import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 import chip_smoke
-from benchmarks.paper_suite import (TOLERANCE, filter_pipeline_sct,
-                                    make_inputs, max_error, reference)
+from benchmarks.paper_suite import (BENCHMARKS, TOLERANCE,
+                                    filter_pipeline_sct, make_inputs,
+                                    max_error, reference)
 from repro.core import (AcceleratorPlatform, DeviceInfo, HostPlatform,
                         KnowledgeBase, Scheduler, ThreadedExecutor)
 from repro.jaxcache import CHECKOUT
@@ -90,6 +93,84 @@ def test_filter_pipeline_split_equals_whole_image():
     assert any(np.cumsum(units)[:-1] % 13)
     assert max_error(run.outputs, reference("filter_pipeline", inputs)) == 0
     sched.close()
+
+
+# ---------------------------------------------------------------------------
+# bytes handed between host memory and the accelerator class
+# ---------------------------------------------------------------------------
+
+#: (h2d, d2h) bytes of one request with ``u`` accelerator units at size
+#: ``n``: the filter's image rows in and its three outputs' rows back;
+#: saxpy's x, y and the float32 scalar a in and z back
+HANDED_BYTES = {"filter_pipeline": lambda u, n: (4 * u * n, 3 * 4 * u * n),
+                "saxpy": lambda u, n: (8 * u + 4, 4 * u)}
+
+
+def _bytes_scheduler(health=None):
+    from repro.core import Telemetry
+    telemetry = Telemetry()
+    sched = Scheduler(host=HostPlatform.from_jax(),
+                      accel=accel_platform(jax.devices()[:1]),
+                      executor=ThreadedExecutor(), kb=KnowledgeBase(),
+                      health=health, telemetry=telemetry)
+    return sched, telemetry
+
+
+@pytest.mark.parametrize("name", sorted(HANDED_BYTES))
+def test_bytes_handed_to_and_from_the_accelerator_follow_the_split(name):
+    n = TINY[name]
+    sched, telemetry = _bytes_scheduler()
+    sct = BENCHMARKS[name][0](n)
+    # the first run learns the output shapes and copies in the merge; the
+    # second writes each slot's outputs straight into the host buffers
+    runs = [sched.run(sct, make_inputs(name, n, seed=s)) for s in (1, 2)]
+    sched.close()
+    assert runs[0].stats.merge_bytes > 0 and runs[1].stats.merge_bytes == 0
+    for run in runs:
+        part = run.node_plan.part
+        u = sum(k for s, k in zip(part.slots, part.units)
+                if s.device_type != "cpu")
+        assert 0 < u < sum(part.units)
+        h2d, d2h = HANDED_BYTES[name](u, n)
+        assert (run.stats.h2d_bytes, run.stats.d2h_bytes) == (h2d, d2h)
+    metrics = telemetry.metrics.snapshot()
+    assert metrics["h2d_bytes_total"] == 2 * h2d
+    assert metrics["d2h_bytes_total"] == 2 * d2h
+
+
+@pytest.mark.parametrize("name", sorted(HANDED_BYTES))
+def test_no_bytes_are_handed_when_every_unit_runs_on_the_host(name):
+    from repro.core.faults import DeviceHealth
+    health = DeviceHealth(quarantine_after=1, probe_after=10 ** 6)
+    health.record_failure("accel0")
+    sched, telemetry = _bytes_scheduler(health)
+    n = TINY[name]
+    run = sched.run(BENCHMARKS[name][0](n), make_inputs(name, n))
+    sched.close()
+    assert {s.device_type for s in run.node_plan.part.slots} == {"cpu"}
+    assert (run.stats.h2d_bytes, run.stats.d2h_bytes) == (0, 0)
+    assert telemetry.metrics.snapshot()["h2d_bytes_total"] == 0
+
+
+def test_the_accelerator_slot_time_splits_into_compute_and_writeback():
+    from repro.core import kernel, vector
+
+    def slow(x):
+        time.sleep(0.1)
+        return jnp.asarray(x) * 2.0
+    sct = kernel(slow, name="slow_double", inputs=[vector("x")],
+                 outputs=[vector("z")])
+    sched, _ = _bytes_scheduler()
+    x = np.arange(4096, dtype=np.float32)
+    # the second run writes the chip's output straight into a host buffer
+    runs = [sched.run(sct, {"x": x}) for _ in range(2)]
+    sched.close()
+    for run in runs:
+        st = run.stats
+        assert st.compute_a >= 0.1 and st.writeback_a >= 0.0
+        assert st.compute_a + st.writeback_a <= st.time_a
+    assert runs[1].stats.writeback_a > 0.0
+    assert runs[1].stats.d2h_bytes > 0
 
 
 # ---------------------------------------------------------------------------

@@ -109,10 +109,13 @@ class TestTracer:
 
     def test_threads_get_distinct_tids(self):
         tr = Tracer()
+        # all three threads are alive at once, so none reuses another's
+        # thread ident
+        together = threading.Barrier(3)
 
         def spin():
             with tr.span("t"):
-                time.sleep(0.01)
+                together.wait(10)
 
         threads = [threading.Thread(target=spin) for _ in range(3)]
         for t in threads:
@@ -418,6 +421,200 @@ class TestCounters:
             c = s.counters()
         assert c["scheduler.resident_handoffs"] == 1    # first chain step
         assert c["scheduler.runs"] == 2
+
+
+# ---------------------------------------------------------------------------
+# Request ids, admission time and the profiler sink
+# ---------------------------------------------------------------------------
+
+#: every span one ``Session.submit`` request opens (``plan`` only when the
+#: request is planned, ``merge`` only when its outputs are merged)
+REQUEST_SPANS = {"submit", "node", "run", "plan", "dispatch", "attempt",
+                 "slot", "compute", "writeback", "merge"}
+#: child span -> parent span on the same thread
+SAME_THREAD_PARENT = {"run": "node", "plan": "run", "dispatch": "run",
+                      "attempt": "dispatch", "merge": "dispatch",
+                      "compute": "slot", "writeback": "slot"}
+
+
+def one_node_graph(sct=None):
+    from repro.core import JobGraph
+    graph = JobGraph()
+    graph.add(sct or saxpy_tree())
+    return graph
+
+
+def chrome_intervals(events):
+    """(name, tid, start, end, args) per span of a Chrome B/E stream; the
+    args are the B event's and the notes on the E event together."""
+    out, stacks = [], {}
+    for e in events:
+        if e["ph"] == "B":
+            stacks.setdefault(e["tid"], []).append(e)
+        elif e["ph"] == "E":
+            b = stacks[e["tid"]].pop()
+            out.append((b["name"], b["tid"], b["ts"], e["ts"],
+                        {**b.get("args", {}), **e.get("args", {})}))
+    return out
+
+
+def queue_seconds(handle):
+    """The admission wait that a one-node request's run carries."""
+    (run,) = handle.runs.values()
+    return run.stats.queue_seconds
+
+
+def assert_nested(spans):
+    """Each span in SAME_THREAD_PARENT lies inside a parent span of its
+    own thread and request; a phase of a slot has the slot's class."""
+    for name, line, s, e, attrs in spans:
+        parent = SAME_THREAD_PARENT.get(name)
+        if parent is None:
+            continue
+        assert any(n == parent and ln == line and ps <= s and e <= pe
+                   and pa["request"] == attrs["request"]
+                   and pa.get("cls") == attrs.get("cls")
+                   for n, ln, ps, pe, pa in spans), (name, attrs)
+
+
+class TestRequestSpans:
+    def test_every_span_of_a_request_carries_its_id(self):
+        tel = Telemetry()
+        with Session(make_scheduler(ThreadedExecutor(policy=POLICY)),
+                     telemetry=tel) as s:
+            handles = [s.submit(one_node_graph(), **saxpy_arrays())
+                       for _ in range(3)]
+            for h in handles:
+                h.result(30)
+        spans = chrome_intervals(tel.tracer.events())
+        assert {n for n, *_ in spans} == REQUEST_SPANS
+        ids = {h.request_id for h in handles}
+        assert {a["request"] for *_, a in spans} == ids
+        assert {a["cls"] for n, *_, a in spans if n == "slot"} == {"a", "b"}
+        assert_nested(spans)
+
+    def test_pool_span_and_counters_are_gone_but_counted(self):
+        tel = Telemetry()
+        ex = ThreadedExecutor(policy=POLICY)
+        with Session(make_scheduler(ex), telemetry=tel) as s:
+            for _ in range(2):
+                s.submit(one_node_graph(), **saxpy_arrays()).result(30)
+            counters = s.counters()
+        assert "pool" not in {e["name"] for e in tel.tracer.events()}
+        assert not [k for k in tel.metrics.snapshot() if "pool" in k]
+        assert counters["executor.pools_created"] == ex.pools_created >= 1
+        assert counters["executor.pool_reuses"] == ex.pool_reuses >= 1
+
+    def test_queued_s_is_the_wait_for_admission(self):
+        release = threading.Event()
+
+        def held(x):
+            release.wait(30)
+            return x + 1
+        slow = kernel(held, name="held", inputs=[vector("x")],
+                      outputs=[vector("z")])
+        sched = make_scheduler(ThreadedExecutor(policy=POLICY),
+                               max_inflight=1)
+        first = sched.submit(one_node_graph(slow),
+                             {"x": np.ones(64, np.float32)})
+        second = sched.submit(one_node_graph(slow),
+                              {"x": np.ones(64, np.float32)})
+        time.sleep(0.2)
+        release.set()
+        first.result(30)
+        second.result(30)
+        sched.close()
+        waited = [queue_seconds(h) for h in (first, second)]
+        assert 0.0 <= waited[0] < 0.2 <= waited[1]
+        # the queue is inside the node's span clock: the node starts
+        # after its graph left the queue
+        (start, _), = second.spans().values()
+        assert start >= waited[1] * 1e6
+
+    def test_a_fused_request_waits_its_fusion_window(self):
+        sched = make_scheduler(ThreadedExecutor(policy=POLICY),
+                               fusion_window=0.1)
+        handles = [sched.submit(one_node_graph(), saxpy_arrays())
+                   for _ in range(2)]
+        for h in handles:
+            h.result(30)
+        sched.close()
+        assert all(run.action == "fused"
+                   for h in handles for run in h.runs.values())
+        # the window opened when the first one joined; the second joined
+        # later and waited less
+        first, second = (queue_seconds(h) for h in handles)
+        assert 0.09 <= first < 5.0 and 0.0 <= second <= first
+
+
+@pytest.fixture(scope="module")
+def profiled_requests(tmp_path_factory):
+    """Three Session requests under ``Telemetry(profiler=True)`` inside a
+    ``jax.profiler`` trace on the CPU: the program's spans as the trace
+    reader sees them, (plane, line, name, start, end, metadata)."""
+    import glob
+    import os
+    import jax
+    from jax.profiler import ProfileData
+    d = str(tmp_path_factory.mktemp("profile"))
+    tel = Telemetry(profiler=True)
+    accel = AcceleratorPlatform([DeviceInfo("accel0", "accel",
+                                            jax_device=jax.devices()[0])])
+    sched = Scheduler(host=HostPlatform.from_jax(), accel=accel,
+                      executor=ThreadedExecutor(policy=POLICY),
+                      kb=KnowledgeBase(), balancer=LoadBalancer(max_dev=0.0))
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(d, profiler_options=opts)
+    try:
+        with Session(sched, telemetry=tel) as s:
+            handles = [s.submit(one_node_graph(), **saxpy_arrays())
+                       for _ in range(3)]
+            for h in handles:
+                h.result(60)
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(os.path.join(d, "**", "*.xplane.pb"), recursive=True)
+    spans = []
+    for plane in ProfileData.from_file(path).planes:
+        for i, line in enumerate(plane.lines):
+            for e in line.events:
+                if e.name.startswith("repro."):
+                    spans.append((plane.name, i, e.name[len("repro."):],
+                                  e.start_ns, e.start_ns + e.duration_ns,
+                                  dict(e.stats)))
+    return spans, {h.request_id for h in handles}, tel
+
+
+class TestProfilerSink:
+    def test_spans_land_on_the_host_plane_with_request_and_class(
+            self, profiled_requests):
+        spans, ids, tel = profiled_requests
+        assert {p for p, *_ in spans} == {"/host:CPU"}
+        assert {n for _, _, n, *_ in spans} == REQUEST_SPANS
+        assert {a["request"] for *_, a in spans} == ids
+        slots = [a for _, _, n, *_, a in spans if n == "slot"]
+        assert {a["cls"] for a in slots} == {"a", "b"}
+        # notes reach the profiler event too
+        assert all("bound" in a and "placed" in a for a in slots)
+        assert_nested([(n, ln, s, e, a) for _, ln, n, s, e, a in spans])
+        # the profiler is the store: nothing went to the Chrome buffer
+        assert tel.tracer.events() == []
+
+    def test_profiler_spans_are_inert_outside_a_trace(self):
+        tel = Telemetry(profiler=True)
+        sched = make_scheduler(ThreadedExecutor(policy=POLICY),
+                               telemetry=tel)
+        run = sched.run(saxpy_tree(), saxpy_arrays())
+        sched.close()
+        np.testing.assert_array_equal(run.outputs["z"],
+                                      2.0 * np.arange(256) + 1)
+        assert tel.tracer.events() == []
+        assert tel.metrics.snapshot()["runs_total{status=ok}"] == 1
+
+    def test_disabled_telemetry_ignores_the_profiler_flag(self):
+        tel = Telemetry(enabled=False, profiler=True)
+        assert tel.tracer.span("slot") is tel.tracer.span("merge")
 
 
 # ---------------------------------------------------------------------------
