@@ -34,6 +34,15 @@ per-dispatch cost:
     ``execute`` are overwritten by the next run on the same executor —
     callers that retain outputs across runs must copy them (or construct
     the executor with ``reuse_buffers=False``).
+  * **overlapped read-back** — an accelerator slot starts the
+    device→host copy of every output it writes into a buffer as soon as
+    ``sct.apply`` returns, all at once (``jax.Array.copy_to_host_async``);
+    an output over ``D2H_BLOCK_BYTES`` is first cut on its device into
+    row blocks of whole (8, 128) tiles, so several copies and host
+    relayouts run together.  The slot then writes the blocks into their
+    rows in order, each through ``np.asarray``, while the later ones are
+    still in flight.  The merge's copies of accelerator outputs take the
+    same path.
   * **partitioned residency** — ``execute(..., keep_resident=True)``
     skips the merge entirely and hands back a :class:`ResidentPartition`
     whose slot-local outputs feed the next SCT's slot-local inputs
@@ -73,14 +82,26 @@ user merge function combines, COPY outputs handed back as they are and
 what a resident chain keeps on the slots are not counted).  The counts
 are taken where the slot receives its values and where its outputs are
 written back, so a copy made anywhere else (an explicit ``device_put``
-before the slot, a read-back by the caller) is not in them.
+before the slot, a read-back by the caller) is not in them.  Cutting an
+output into blocks changes none of these bytes; ``d2h_blocks`` counts
+the device→host copies the read-back started (one per output read
+whole, one per block of a cut one).
 
 Each ``slot`` span (``cls="a"`` for accelerator-class slots, ``"b"`` for
 the host class) has two children, also timed on every run: ``compute``
-(segment environment, ``sct.apply`` and ``block_until_ready``, so the
-implicit upload of host inputs) and ``writeback`` (the direct write into
-host buffers, so the read-back).  The run's ``compute_a`` /
-``writeback_a`` are those of the accelerator slot with the longest time.
+(segment environment, ``sct.apply``, the start of the read-back and
+``block_until_ready``, so the implicit upload of host inputs) and
+``writeback`` (waiting for the read-back's copies and writing them into
+host buffers; it notes ``blocks``, the copies).  The run's
+``compute_a`` / ``writeback_a`` are those of the accelerator slot with
+the longest time.
+
+At the end of every run the process's :data:`repro.heap.GUARD` reads the
+resident set and, once it has grown by ``repro.heap.SLACK_BYTES``, hands
+freed heap back to the system on a thread of its own (counter
+``heap_releases_total``): under ``jax.profiler`` the host slot's freed
+XLA:CPU buffers would otherwise stay resident, about 128 MiB a 4096² px
+filter request.
 
 Failure semantics
 -----------------
@@ -106,6 +127,7 @@ from __future__ import annotations
 import concurrent.futures as cf
 import contextlib
 import dataclasses
+import functools
 import threading
 import time
 from typing import (Any, Callable, Dict, Iterable, List, Optional, Sequence,
@@ -114,6 +136,7 @@ from typing import (Any, Callable, Dict, Iterable, List, Optional, Sequence,
 import jax
 import numpy as np
 
+from repro import heap
 from repro.core.decomposition import ConcretePartitioning
 from repro.core.faults import (ExecutionError, FaultInjector, FaultPolicy,
                                FaultRecord, InjectedFault, split_units)
@@ -122,6 +145,16 @@ from repro.core.knowledge_base import Profile
 from repro.core.skeletons import SCT, PartitionInfo
 from repro.core.spec import ArgSpec, MergeFn, Transfer, Workload
 from repro.core.telemetry import NULL_TELEMETRY, Telemetry
+
+#: An accelerator output larger than this many bytes is read back in row
+#: blocks of about this size, so that their copies run at once.  On a v5e
+#: chip 8 MiB blocks read the filter's 54 MB outputs back in half the time
+#: whole outputs take, as fast as 4 MiB with half the copies, while 2 MiB
+#: costs more to start than it saves (PERF.md, Findings PR 14)
+D2H_BLOCK_BYTES = 8 << 20
+#: rows of a TPU (8, 128) tile, and elements of a 1-D array's tile
+_TILE_ROWS = 8
+_TILE_ELEMS = 8 * 128
 
 
 def output_spec(sct: SCT, name: str) -> Optional[ArgSpec]:
@@ -153,6 +186,7 @@ class ExecResult:
     n_a: int                                # accelerator-class slot count
     h2d_bytes: int = 0                      # host values to class-a slots
     d2h_bytes: int = 0                      # class-a outputs read to host
+    d2h_blocks: int = 0                     # device→host copies started
     compute_a: float = 0.0                  # slowest class-a slot: compute
     writeback_a: float = 0.0                # ... and its write-back
 
@@ -164,6 +198,7 @@ class _SlotResult:
     written: frozenset = frozenset()    # outputs direct-written to buffers
     h2d_bytes: int = 0                  # host values handed to the slot
     d2h_bytes: int = 0                  # outputs direct-written to host
+    d2h_blocks: int = 0                 # device→host copies it started
     compute_s: float = 0.0              # segment env, apply, ready
     writeback_s: float = 0.0            # direct write to host buffers
 
@@ -475,6 +510,10 @@ class ThreadedExecutor:
                 with self._buf_lock:
                     for b in leases:
                         self._inuse.discard(id(b))
+            # the host slot's freed XLA:CPU buffers can stay resident
+            # (under a profiler): hand them back once they add up
+            if heap.GUARD.check():
+                self.telemetry.metrics.counter("heap_releases_total").inc()
 
     def _execute_leased(self, sct: SCT, part: ConcretePartitioning,
                         arrays: Dict[str, Any], profile: Profile,
@@ -586,6 +625,7 @@ class ThreadedExecutor:
         resident_out: Optional[ResidentPartition] = None
         direct_bytes = 0
         d2h_bytes = sum(res.d2h_bytes for _, res in done)
+        d2h_blocks = sum(res.d2h_blocks for _, res in done)
         if keep_resident and clean:
             with tel.tracer.span("resident-handoff", request=request,
                                  segments=len(done)):
@@ -594,11 +634,12 @@ class ThreadedExecutor:
             outputs: Dict[str, Any] = {}
         else:
             with tel.tracer.span("merge", request=request) as merge_span:
-                outputs, copied, direct_bytes, read_back = self._merge(
-                    sct, part, done, targets, leases)
+                outputs, copied, direct_bytes, read_back, blocks = \
+                    self._merge(sct, part, done, targets, leases)
                 merge_span.note(merge_bytes=copied)
             merge_bytes += copied
             d2h_bytes += read_back
+            d2h_blocks += blocks
             if inherited_extras and keep_resident:
                 # chain fallback: surface carried values with the merge
                 outputs = {**inherited_extras, **outputs}
@@ -623,8 +664,8 @@ class ThreadedExecutor:
             timing=timing, merge_bytes=merge_bytes,
             direct_bytes=direct_bytes, resident=resident_out, n_a=n_a,
             h2d_bytes=sum(res.h2d_bytes for _, res in done),
-            d2h_bytes=d2h_bytes, compute_a=compute_a,
-            writeback_a=writeback_a)
+            d2h_bytes=d2h_bytes, d2h_blocks=d2h_blocks,
+            compute_a=compute_a, writeback_a=writeback_a)
 
     def _run_attempt(self, sct: SCT, part: ConcretePartitioning,
                      arrays: Dict[str, Any], segments: Sequence[_Segment],
@@ -665,6 +706,10 @@ class ThreadedExecutor:
                         with (jax.default_device(dev) if dev is not None
                               else contextlib.nullcontext()):
                             out_env = sct.apply(env)
+                            # every copy back to the host starts now,
+                            # before the wait for the outputs
+                            reads = {n: _ReadBack(out_env[n]) for n in targets
+                                     if accel and _readable(out_env.get(n))}
                             for v in out_env.values():
                                 if hasattr(v, "block_until_ready"):
                                     v.block_until_ready()
@@ -673,14 +718,19 @@ class ThreadedExecutor:
                         sp.note(bound=None if dev is None else str(dev),
                                 placed=_placement(out_env, produced))
                     t_w = time.perf_counter()
-                    with tracer.span("writeback", request=request, cls=cls):
-                        written = self._direct_write(out_env, seg, targets)
+                    with tracer.span("writeback", request=request,
+                                     cls=cls) as wsp:
+                        written = self._direct_write({**out_env, **reads},
+                                                     seg, targets)
+                        blocks = sum(len(r.blocks) for r in reads.values())
+                        wsp.note(blocks=blocks)
                     t1 = time.perf_counter()
                     return _SlotResult(
                         out_env, t1 - t0, written,
                         h2d_bytes=_host_bytes(env.values()) if accel else 0,
                         d2h_bytes=_device_bytes(out_env[n] for n in written)
                         if accel else 0,
+                        d2h_blocks=blocks,
                         compute_s=t_c1 - t_c, writeback_s=t1 - t_w)
                 except Exception as e:   # containment: never crosses the slot
                     sp.note(fault=type(e).__name__)
@@ -873,7 +923,8 @@ class ThreadedExecutor:
     def _direct_write(self, out_env: Dict[str, Any], seg: _Segment,
                       targets: Dict[str, _OutputTarget]) -> frozenset:
         """Write this segment's partitionable outputs straight into the
-        preallocated buffers (zero-copy merge); returns the names written."""
+        preallocated buffers (zero-copy merge); returns the names written.
+        An accelerator output arrives as its started :class:`_ReadBack`."""
         if not targets:
             return frozenset()
         written = set()
@@ -890,7 +941,10 @@ class ThreadedExecutor:
             dst = tg.buffer[tuple(idx)]
             if np.shape(v) != dst.shape:
                 continue
-            dst[...] = v        # single device→buffer conversion + copy
+            if isinstance(v, _ReadBack):
+                v.write(dst)
+            else:
+                dst[...] = v    # single conversion + copy
             written.add(name)
         return frozenset(written)
 
@@ -899,10 +953,10 @@ class ThreadedExecutor:
                done: Sequence[Tuple[_Segment, _SlotResult]],
                targets: Optional[Dict[str, _OutputTarget]] = None,
                leases: Optional[List[np.ndarray]] = None
-               ) -> Tuple[Dict[str, Any], int, int, int]:
+               ) -> Tuple[Dict[str, Any], int, int, int, int]:
         """Merge per-segment outputs; returns (outputs, bytes copied,
         bytes direct-written, bytes of accelerator-class outputs the
-        copies read into host memory).
+        copies read into host memory, device→host copies started).
 
         Precedence per output name (documented contract):
           1. a user-supplied merge function (``self.merges``) — honoured
@@ -918,6 +972,7 @@ class ThreadedExecutor:
         bytes_copied = 0
         direct_bytes = 0
         read_back = 0
+        blocks = 0
         accel = {j for j, s in enumerate(part.slots)
                  if s.device_type != "cpu"}
         sid = sct.unique_id()
@@ -944,25 +999,27 @@ class ThreadedExecutor:
                      for p in parts], axis=axis)
                 bytes_copied += merged[name].nbytes
                 continue
-            out, copied, direct = self._assemble(
-                name, axis, pieces, targets.get(name), leases)
+            out, copied, direct, started = self._assemble(
+                name, axis, pieces, targets.get(name), leases, accel)
             merged[name] = out
             bytes_copied += copied
             direct_bytes += direct
+            blocks += started
             self._out_shapes[(sid, name)] = (tuple(out.shape), out.dtype)
-        return merged, bytes_copied, direct_bytes, read_back
+        return merged, bytes_copied, direct_bytes, read_back, blocks
 
     def _assemble(self, name: str, axis: int,
                   pieces: Sequence[Tuple[_Segment, _SlotResult]],
                   target: Optional[_OutputTarget],
-                  leases: List[np.ndarray]
-                  ) -> Tuple[np.ndarray, int, int]:
+                  leases: List[np.ndarray], accel: set
+                  ) -> Tuple[np.ndarray, int, int, int]:
         """In-place assembly of one partitionable output.
 
-        Returns (array, bytes copied here, bytes already direct-written).
-        Segments that wrote into the target buffer during compute are
-        skipped; anything else is packed with a single conversion+copy
-        per part (no ``np.asarray`` round trip, no concat temporary)."""
+        Returns (array, bytes copied here, bytes already direct-written,
+        device→host copies started).  Segments that wrote into the target
+        buffer during compute are skipped; anything else is packed with
+        one copy per part (no concat temporary), the parts of accelerator
+        slots (``accel``, slot indices) through a :class:`_ReadBack`."""
         parts = [res.outputs[name] for _, res in pieces]
         sizes = [int(np.shape(p)[axis]) for p in parts]
         if target is not None:
@@ -971,6 +1028,7 @@ class ThreadedExecutor:
                 for s, (seg, _) in zip(sizes, pieces))
             if expected and target.buffer.shape[axis] == sum(sizes):
                 copied = direct = 0
+                fills = []
                 for (seg, res), p, s in zip(pieces, parts, sizes):
                     off = seg.start * target.epu
                     idx = [slice(None)] * target.buffer.ndim
@@ -981,9 +1039,10 @@ class ThreadedExecutor:
                     if name in res.written:
                         direct += n
                         continue
-                    target.buffer[tuple(idx)] = p
+                    fills.append((seg.slot in accel, p,
+                                  target.buffer[tuple(idx)]))
                     copied += n
-                return target.buffer, copied, direct
+                return target.buffer, copied, direct, _fill(fills)
         # no (usable) target: learn the shape, pack into a reusable buffer
         first = parts[0]
         shape = list(np.shape(first))
@@ -993,13 +1052,14 @@ class ThreadedExecutor:
         buf = self._get_buffer(name, tuple(shape), dtype, leases)
         off = 0
         copied = 0
-        for p, s in zip(parts, sizes):
+        fills = []
+        for (seg, _), p, s in zip(pieces, parts, sizes):
             idx = [slice(None)] * buf.ndim
             idx[axis] = slice(off, off + s)
-            buf[tuple(idx)] = p
-            copied += buf[tuple(idx)].nbytes
+            fills.append((seg.slot in accel, p, buf[tuple(idx)]))
+            copied += fills[-1][2].nbytes
             off += s
-        return buf, copied, 0
+        return buf, copied, 0, _fill(fills)
 
     # -- residency -------------------------------------------------------------
     def _make_resident(self, sct: SCT, part: ConcretePartitioning,
@@ -1075,6 +1135,71 @@ def _host_bytes(values: Iterable[Any]) -> int:
 def _device_bytes(values: Iterable[Any]) -> int:
     """Bytes of the ``jax.Array`` values."""
     return sum(v.nbytes for v in values if isinstance(v, jax.Array))
+
+
+def _row_bounds(rows: int, row_bytes: int, align: int
+                ) -> Tuple[Tuple[int, int], ...]:
+    """Row ranges in which an output of ``rows`` rows of ``row_bytes``
+    each is read back: whole when it fits in one ``D2H_BLOCK_BYTES``
+    block, else blocks of a multiple of ``align`` rows, the last taking
+    the remainder."""
+    step = max(align, D2H_BLOCK_BYTES // max(row_bytes, 1) // align * align)
+    if rows <= step:
+        return ((0, rows),)
+    return tuple((a, min(a + step, rows)) for a in range(0, rows, step))
+
+
+@functools.lru_cache(maxsize=64)
+def _cutter(bounds: Tuple[Tuple[int, int], ...]):
+    """One device program that cuts an array into the row ``bounds``."""
+    return jax.jit(lambda v: tuple(v[a:b] for a, b in bounds))
+
+
+def _readable(v: Any) -> bool:
+    """Whether ``v`` is an output the read-back takes (a ``jax.Array``
+    with rows)."""
+    return isinstance(v, jax.Array) and v.ndim >= 1
+
+
+class _ReadBack:
+    """The device→host copy of one accelerator output, started at once:
+    whole, or, when the output is larger than ``D2H_BLOCK_BYTES``, cut on
+    its device into row blocks of whole (8, 128) tiles (a 1-D output into
+    runs of 8 × 128 elements), every block's copy in flight together.
+    ``shape`` and ``ndim`` are the output's, so it stands in for the
+    output where :meth:`ThreadedExecutor._direct_write` writes it."""
+
+    def __init__(self, value: jax.Array):
+        self.shape, self.ndim = value.shape, value.ndim
+        rows = value.shape[0]
+        bounds = _row_bounds(rows, value.nbytes // max(rows, 1),
+                             _TILE_ROWS if value.ndim > 1 else _TILE_ELEMS)
+        pieces = _cutter(bounds)(value) if len(bounds) > 1 else (value,)
+        for p in pieces:
+            p.copy_to_host_async()
+        self.blocks = list(zip(pieces, bounds))
+
+    def write(self, dst: np.ndarray) -> None:
+        """Each block into its rows of ``dst``, in order, as its copy lands
+        (the later ones still in flight).  ``np.asarray`` first: numpy,
+        handed a TPU ``jax.Array`` itself, asks for its buffer, is refused,
+        and takes three times as long (PERF.md, Findings PR 14)."""
+        for p, (a, b) in self.blocks:
+            dst[a:b] = np.asarray(p)
+
+
+def _fill(fills: Sequence[Tuple[bool, Any, np.ndarray]]) -> int:
+    """Copy ``(from an accelerator slot, value, destination)`` parts into
+    host buffers, the accelerator's through a :class:`_ReadBack` (all
+    started before the host's are copied); returns the device→host
+    copies."""
+    reads = [(_ReadBack(v), d) for a, v, d in fills if a and _readable(v)]
+    for a, v, d in fills:
+        if not (a and _readable(v)):
+            d[...] = v
+    for r, d in reads:
+        r.write(d)
+    return sum(len(r.blocks) for r, _ in reads)
 
 
 def _produced_names(sct: SCT) -> List[str]:

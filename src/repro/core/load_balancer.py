@@ -61,7 +61,9 @@ class ExecutionStats:
     ``compute_a`` / ``writeback_a`` split ``time_a`` into the two phases
     of the accelerator slot that set it: computing its outputs (the
     implicit upload of host inputs included) and writing them back into
-    host buffers (see :mod:`repro.core.executor`).  ``queue_seconds`` is
+    host buffers (see :mod:`repro.core.executor`); ``d2h_blocks`` counts
+    the device→host copies the read-back of those outputs started (one
+    per output, or one per row block of a large one).  ``queue_seconds`` is
     how long the request this run belongs to waited for admission in
     the Scheduler (see :class:`~repro.core.graph.GraphHandle`).
     """
@@ -82,6 +84,7 @@ class ExecutionStats:
     resident: bool = False       # outputs left slot-resident (merge skipped)
     h2d_bytes: int = 0           # host values handed to accelerator slots
     d2h_bytes: int = 0           # accelerator outputs read into host memory
+    d2h_blocks: int = 0          # device→host copies started for them
     compute_a: float = 0.0       # time_a's slot: segment compute
     writeback_a: float = 0.0     # time_a's slot: write-back to host buffers
     queue_seconds: float = 0.0   # the request's wait for admission

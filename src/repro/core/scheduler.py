@@ -569,6 +569,7 @@ class Scheduler:
         tel.metrics.counter("merge_bytes_total").inc(stats.merge_bytes)
         tel.metrics.counter("h2d_bytes_total").inc(stats.h2d_bytes)
         tel.metrics.counter("d2h_bytes_total").inc(stats.d2h_bytes)
+        tel.metrics.counter("d2h_blocks_total").inc(stats.d2h_blocks)
         tel.metrics.histogram("class_makespan_seconds",
                               cls="a").observe(stats.time_a)
         tel.metrics.histogram("class_makespan_seconds",
@@ -1202,7 +1203,7 @@ class Scheduler:
         if getattr(self.executor, "supports_residency", False):
             kwargs = {"resident": resident, "keep_resident": keep_resident}
         execute_result = getattr(self.executor, "execute_result", None)
-        h2d_bytes = d2h_bytes = 0
+        h2d_bytes = d2h_bytes = d2h_blocks = 0
         compute_a = writeback_a = 0.0
         if execute_result is not None:
             # per-call result object: safe under concurrent graph nodes
@@ -1213,6 +1214,7 @@ class Scheduler:
             timing = dict(res.timing or {})
             merge_bytes = res.merge_bytes
             h2d_bytes, d2h_bytes = res.h2d_bytes, res.d2h_bytes
+            d2h_blocks = res.d2h_blocks
             compute_a, writeback_a = res.compute_a, res.writeback_a
             resident_out = res.resident
         else:
@@ -1238,7 +1240,7 @@ class Scheduler:
             merge_bytes=merge_bytes,
             plan_cache_hit=cache_hit,
             resident=resident_out is not None,
-            h2d_bytes=h2d_bytes, d2h_bytes=d2h_bytes,
+            h2d_bytes=h2d_bytes, d2h_bytes=d2h_bytes, d2h_blocks=d2h_blocks,
             compute_a=compute_a, writeback_a=writeback_a)
         return outputs, stats, list(slots), resident_out, node_plan
 

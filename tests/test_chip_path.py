@@ -160,7 +160,7 @@ def test_the_accelerator_slot_time_splits_into_compute_and_writeback():
         return jnp.asarray(x) * 2.0
     sct = kernel(slow, name="slow_double", inputs=[vector("x")],
                  outputs=[vector("z")])
-    sched, _ = _bytes_scheduler()
+    sched, telemetry = _bytes_scheduler()
     x = np.arange(4096, dtype=np.float32)
     # the second run writes the chip's output straight into a host buffer
     runs = [sched.run(sct, {"x": x}) for _ in range(2)]
@@ -169,8 +169,21 @@ def test_the_accelerator_slot_time_splits_into_compute_and_writeback():
         st = run.stats
         assert st.compute_a >= 0.1 and st.writeback_a >= 0.0
         assert st.compute_a + st.writeback_a <= st.time_a
+        assert st.compute_a + st.writeback_a == pytest.approx(st.time_a,
+                                                              abs=0.01)
     assert runs[1].stats.writeback_a > 0.0
     assert runs[1].stats.d2h_bytes > 0
+    # the write-back span notes the device→host copies it waited for
+    open_spans, notes = {}, []
+    for e in telemetry.tracer.events():
+        if e["name"] != "writeback":
+            continue
+        if e["ph"] == "B":
+            open_spans[e["tid"]] = e["args"]["cls"]
+        elif e["ph"] == "E" and open_spans.pop(e["tid"]) == "a":
+            notes.append(e["args"]["blocks"])
+    assert len(notes) == 2 and notes[1] >= 1
+    assert runs[1].stats.d2h_blocks == notes[1]
 
 
 # ---------------------------------------------------------------------------

@@ -382,3 +382,117 @@ class TestZeroShareFallback:
         shares = sched._per_slot_shares(prof, slots)   # no ZeroDivisionError
         assert shares == pytest.approx([1.0 / len(slots)] * len(slots))
         assert sum(shares) == pytest.approx(1.0)
+
+
+# ---------------------------------------------------------------------------
+# Blocked read-back of accelerator outputs
+# ---------------------------------------------------------------------------
+
+#: a block size small enough to cut the test's outputs: 1024 rows of 16
+#: float32, or 16384 elements of a 1-D output
+SMALL_BLOCK = 64 << 10
+#: a block size no output reaches: every output read back whole, as one
+#: ``dst[...] = v`` per output did
+WHOLE = 1 << 40
+
+
+def read_back_tree():
+    import jax.numpy as jnp
+    return kernel(lambda x: (jnp.asarray(x) * 2.0 + 1.0,
+                             jnp.asarray(x) * -3.0),
+                  name="two_maps", inputs=[vector("x")],
+                  outputs=[vector("z"), vector("w")])
+
+
+def read_back_scheduler(n_accel=1, *, reuse_buffers=True, injector=None,
+                        telemetry=None):
+    """Accelerator-class slots on JAX's CPU device (kind ``accel``, so
+    their outputs are ``jax.Array`` values read back into host buffers),
+    beside one host slot."""
+    import jax
+    dev = jax.devices()[0]
+    accel = AcceleratorPlatform([DeviceInfo(f"accel{i}", "accel",
+                                            jax_device=dev)
+                                 for i in range(n_accel)])
+    host = HostPlatform(DeviceInfo("cpu0", "cpu", compute_units=1),
+                        topology={"L2": 1, "NO_FISSION": 1})
+    ex = ThreadedExecutor(policy=POLICY, reuse_buffers=reuse_buffers,
+                          injector=injector)
+    return Scheduler(host=host, accel=accel, executor=ex, kb=KnowledgeBase(),
+                     balancer=LoadBalancer(max_dev=0.0), telemetry=telemetry)
+
+
+def accel_units(run):
+    part = run.node_plan.part
+    return sum(u for s, u in zip(part.slots, part.units)
+               if s.device_type != "cpu")
+
+
+#: case -> (input shape, scheduler keywords, crash on the accelerator)
+READ_BACK_CASES = {
+    "rows_3277": ((3277, 16), {}, False),
+    "one_d": ((40000,), {}, False),
+    "under_one_block": ((3277, 4), {}, False),
+    "no_buffer_reuse": ((3277, 16), {"reuse_buffers": False}, False),
+    "fault_retry": ((3277, 16), {"n_accel": 2}, True),
+}
+
+
+class TestBlockedReadBack:
+    def run_case(self, monkeypatch, block, shape, kw, crash):
+        from repro.core import executor
+        monkeypatch.setattr(executor, "D2H_BLOCK_BYTES", block)
+        x = np.arange(int(np.prod(shape)), dtype=np.float32
+                      ).reshape(shape) / 7.0
+        # the second request crashes accel0: its units are re-split over
+        # the surviving accelerator and the host, and written again
+        inj = FaultInjector(crash_on_call={"accel0": [2]}) if crash else None
+        sched = read_back_scheduler(injector=inj, **kw)
+        runs, outs = [], []
+        # the first request learns the output buffers through the merge,
+        # the second writes straight into them
+        for _ in range(2):
+            r = sched.run(read_back_tree(), {"x": x})
+            runs.append(r)
+            outs.append({k: np.copy(v) for k, v in r.outputs.items()})
+        sched.close()
+        return x, runs, outs
+
+    @pytest.mark.parametrize("case", sorted(READ_BACK_CASES))
+    def test_blocked_write_back_is_bit_identical_to_whole(self, case,
+                                                          monkeypatch):
+        shape, kw, crash = READ_BACK_CASES[case]
+        x, runs, blocked = self.run_case(monkeypatch, SMALL_BLOCK, shape,
+                                         kw, crash)
+        _, _, whole = self.run_case(monkeypatch, WHOLE, shape, kw, crash)
+        assert runs[1].stats.retries == (1 if crash else 0)
+        for got, ref in zip(blocked, whole):
+            for name in ("z", "w"):
+                assert got[name].tobytes() == ref[name].tobytes()
+        np.testing.assert_array_equal(blocked[1]["z"], x * 2.0 + 1.0)
+        np.testing.assert_array_equal(blocked[1]["w"], x * -3.0)
+        cut = case != "under_one_block"
+        for r in runs:
+            # both outputs of each accelerator segment: cut, or whole
+            assert (r.stats.d2h_blocks > 2) == cut
+
+    @pytest.mark.parametrize("block", [SMALL_BLOCK, WHOLE])
+    def test_blocks_counted_and_bytes_unchanged(self, block, monkeypatch):
+        from repro.core import Telemetry, executor
+        monkeypatch.setattr(executor, "D2H_BLOCK_BYTES", block)
+        telemetry = Telemetry()
+        sched = read_back_scheduler(telemetry=telemetry)
+        x = np.ones((3277, 16), np.float32)
+        runs = [sched.run(read_back_tree(), {"x": x}) for _ in range(2)]
+        sched.close()
+        for r in runs:
+            u = accel_units(r)
+            # 1024 rows of 64 B fill one small block
+            per_output = -(-u // 1024) if block == SMALL_BLOCK else 1
+            assert r.stats.d2h_blocks == 2 * per_output
+            assert r.stats.d2h_bytes == 2 * u * 16 * 4
+        snap = telemetry.metrics.snapshot()
+        assert snap["d2h_blocks_total"] == sum(r.stats.d2h_blocks
+                                               for r in runs)
+        assert snap["d2h_bytes_total"] == sum(r.stats.d2h_bytes
+                                              for r in runs)

@@ -68,3 +68,25 @@ def test_fft_sct_body_compiles_for_v5e(one_chip):
     text = _compile_text(lambda sig: sct.apply({"sig": sig})["sig_out"],
                          (SIZES["fft"], FFT_ELEMS), sharding=one_chip)
     assert "fft" in text.lower()
+
+
+#: output shapes the accelerator slot cuts before its read-back: one filter
+#: output (3277 of 4096 rows at the default split), and saxpy's ``z`` at
+#: 10^7 elements (8 * 10^6 on the chip), a 1-D output cut in tile runs
+READ_BACK_SHAPES = {"filter_rows": (3277, 4096), "saxpy_1d": (8_000_000,)}
+
+
+@pytest.mark.parametrize("case", sorted(READ_BACK_SHAPES))
+def test_read_back_row_cut_compiles_for_v5e(case, one_chip):
+    from repro.core.executor import (_TILE_ELEMS, _TILE_ROWS, _cutter,
+                                     _row_bounds)
+    shape = READ_BACK_SHAPES[case]
+    align = _TILE_ROWS if len(shape) > 1 else _TILE_ELEMS
+    row_bytes = 4 * (shape[1] if len(shape) > 1 else 1)
+    bounds = _row_bounds(shape[0], row_bytes, align)
+    assert len(bounds) > 1
+    assert all(a % align == 0 for a, _ in bounds)
+    assert bounds[-1][1] == shape[0]
+    x = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    text = _cutter(bounds).lower(x).compile().as_text()
+    assert text.count("slice") >= len(bounds)
