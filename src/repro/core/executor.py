@@ -36,7 +36,7 @@ per-dispatch cost:
     the executor with ``reuse_buffers=False``).
   * **overlapped read-back** — an accelerator slot starts the
     device→host copy of every output it writes into a buffer as soon as
-    ``sct.apply`` returns, all at once (``jax.Array.copy_to_host_async``);
+    its program returns, all at once (``jax.Array.copy_to_host_async``);
     an output over ``D2H_BLOCK_BYTES`` is first cut on its device into
     row blocks of whole (8, 128) tiles, so several copies and host
     relayouts run together.  The slot then writes the blocks into their
@@ -61,12 +61,20 @@ Direct slot writes assume deterministic kernels (a timed-out slot retried
 elsewhere re-produces the same bytes); merged results are bit-identical
 to the historical ``np.concatenate`` merge.
 
-Device binding
---------------
-A slot whose device (``ExecutionSlot.info.jax_device``) is set runs
-``sct.apply`` under ``jax.default_device`` of that device — a
-thread-local setting, so concurrent slots do not interfere — and its JAX
-kernels compute there.
+Compiled bodies and device binding
+----------------------------------
+Every slot runs its segment through ``sct.compiled_apply``: the SCT's
+whole body is one ``jax.jit`` kept on the SCT object, compiled once per
+(SCT object, device, segment shapes and dtypes) — a recurrent request
+reuses the program, and so does another offset of the same shape, since
+the OFFSET trait is a traced scalar while SIZE stays a static int.  No
+slot runs the tree op by op.  Registry counters
+``sct_programs_compiled_total`` (a run that traced and compiled a new
+program) and ``sct_program_runs_total`` (every slot run) say how often
+that happens, and each ``compute`` span notes ``compiled``.
+A slot whose device (``ExecutionSlot.info.jax_device``) is set runs its
+program under ``jax.default_device`` of that device — a thread-local
+setting, so concurrent slots do not interfere — and computes there.
 Only the compute moves: inputs still arrive as host views, and outputs
 are merged into host buffers.  With telemetry on, each ``slot`` span
 notes ``bound`` (the slot's device) and ``placed`` (the devices that hold
@@ -89,8 +97,9 @@ whole, one per block of a cut one).
 
 Each ``slot`` span (``cls="a"`` for accelerator-class slots, ``"b"`` for
 the host class) has two children, also timed on every run: ``compute``
-(segment environment, ``sct.apply``, the start of the read-back and
-``block_until_ready``, so the implicit upload of host inputs) and
+(segment environment, the compiled program with the implicit upload of
+host inputs, the start of the read-back and ``block_until_ready``; it
+notes ``compiled``) and
 ``writeback`` (waiting for the read-back's copies and writing them into
 host buffers; it notes ``blocks``, the copies).  The run's
 ``compute_a`` / ``writeback_a`` are those of the accelerator slot with
@@ -199,7 +208,7 @@ class _SlotResult:
     h2d_bytes: int = 0                  # host values handed to the slot
     d2h_bytes: int = 0                  # outputs direct-written to host
     d2h_blocks: int = 0                 # device→host copies it started
-    compute_s: float = 0.0              # segment env, apply, ready
+    compute_s: float = 0.0              # segment env, program, ready
     writeback_s: float = 0.0            # direct write to host buffers
 
 
@@ -333,6 +342,11 @@ class ResidentPartition:
 
 class ThreadedExecutor:
     """Executes SCT partitions on host threads and times each slot.
+
+    Each slot runs the SCT's body as one compiled program per (SCT
+    object, device, segment shapes and dtypes), with the partition's
+    OFFSET traced and its SIZE static (module docstring); there is no
+    op-by-op path.
 
     ``injector`` (optional) deterministically injects crashes/stalls for
     fault-tolerance experiments; ``policy`` bounds the retry ladder and
@@ -679,6 +693,7 @@ class ThreadedExecutor:
         targets = targets or {}
         pool_sec = pool_sec if pool_sec is not None else [0.0]
         tracer = self.telemetry.tracer
+        metrics = self.telemetry.metrics
         traced = tracer.enabled
         produced = _produced_names(sct) if traced else []
 
@@ -701,11 +716,12 @@ class ThreadedExecutor:
                             time.sleep(self.injector.stall_seconds)
                     dev = slot.info.jax_device if slot.info else None
                     t_c = time.perf_counter()
-                    with tracer.span("compute", request=request, cls=cls):
+                    with tracer.span("compute", request=request,
+                                     cls=cls) as csp:
                         env = self._segment_env(part, arrays, seg, resident)
                         with (jax.default_device(dev) if dev is not None
                               else contextlib.nullcontext()):
-                            out_env = sct.apply(env)
+                            out_env, compiled = sct.compiled_apply(env)
                             # every copy back to the host starts now,
                             # before the wait for the outputs
                             reads = {n: _ReadBack(out_env[n]) for n in targets
@@ -713,7 +729,11 @@ class ThreadedExecutor:
                             for v in out_env.values():
                                 if hasattr(v, "block_until_ready"):
                                     v.block_until_ready()
+                        csp.note(compiled=compiled)
                     t_c1 = time.perf_counter()
+                    metrics.counter("sct_program_runs_total").inc()
+                    if compiled:
+                        metrics.counter("sct_programs_compiled_total").inc()
                     if traced:
                         sp.note(bound=None if dev is None else str(dev),
                                 placed=_placement(out_env, produced))

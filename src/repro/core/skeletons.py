@@ -14,11 +14,20 @@ TPU kernel); ``Loop`` lowers to ``jax.lax.while_loop`` / ``scan``;
 reduction.  Data flows between kernels through a named environment — two
 kernels naming the same vector share an SCT *edge*, which the
 locality-aware decomposition keeps resident (sharding-stable) on device.
+
+An executor runs a tree through :meth:`SCT.compiled_apply`: the whole
+body is one ``jax.jit`` per SCT object, so XLA fuses its kernels, and
+JAX compiles it once per (device, input shapes and dtypes).  Of the
+partition traits, OFFSET is a traced scalar operand, so every offset of
+a segment shape shares one program; SIZE is a static Python int, which
+the segment's shape fixes anyway.  Every kernel body must therefore be
+traceable (``jax.numpy``, ``lax``, Pallas), never NumPy on its inputs.
 """
 from __future__ import annotations
 
 import dataclasses
 import itertools
+import threading
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
@@ -29,6 +38,12 @@ from repro.core.spec import ArgSpec, KernelSpec, Trait, Transfer
 Env = Dict[str, Any]
 
 _node_counter = itertools.count()
+
+#: builds each tree's program once, whatever thread asks first
+_program_lock = threading.Lock()
+#: per thread: set while a tree's body is traced, which is when JAX
+#: compiles a program for it
+_tracing = threading.local()
 
 
 @dataclasses.dataclass
@@ -92,13 +107,51 @@ class SCT:
         inner = ",".join(c._structure() for c in self.children())
         return f"{type(self).__name__.lower()}({inner})"
 
-    # -- convenience --------------------------------------------------------
-    def as_function(self) -> Callable[..., Env]:
-        """Pure function env -> env (jit-able)."""
-        def fn(env: Env) -> Env:
-            return self.apply(dict(env))
-        return fn
+    # -- compiled execution -------------------------------------------------
+    def compiled_apply(self, env: Env) -> Tuple[Env, bool]:
+        """Run the whole tree over ``env`` as one compiled program.
 
+        The program is one ``jax.jit`` of the body, built on this SCT
+        object's first call and kept for its lifetime (two structurally
+        identical trees with other kernel functions never share it).
+        JAX compiles it once per (default device, input shapes and
+        dtypes, SIZE); the partition's OFFSET is a traced scalar.  Runs
+        on ``jax.default_device`` as the caller has set it.  Returns the
+        environment with every value the tree produced or replaced (as
+        ``jax.Array``; unchanged inputs stay as given) and whether this
+        call compiled."""
+        info: Optional[PartitionInfo] = env.get("__partition__")
+        arrays = {k: v for k, v in env.items() if k != "__partition__"}
+        _tracing.fresh = False
+        produced = self.program()(arrays,
+                                  None if info is None else info.offset,
+                                  None if info is None else info.size)
+        return {**env, **produced}, _tracing.fresh
+
+    def program(self) -> Callable[..., Env]:
+        """This tree's ``jax.jit``: (arrays, OFFSET, static SIZE) ->
+        the values the tree produced or replaced."""
+        program = self.__dict__.get("_program")
+        if program is None:
+            with _program_lock:
+                program = self.__dict__.get("_program")
+                if program is None:
+                    program = self._program = jax.jit(
+                        self._body, static_argnums=2)
+        return program
+
+    def _body(self, arrays: Env, offset: Any, size: Optional[int]) -> Env:
+        _tracing.fresh = True
+        env = dict(arrays)
+        if size is not None:
+            env["__partition__"] = PartitionInfo(size=size, offset=offset)
+        out = self.apply(env)
+        # an input handed back unchanged would cost the program a copy
+        return {k: v for k, v in out.items()
+                if k != "__partition__"
+                and (k not in arrays or v is not arrays[k])}
+
+    # -- convenience --------------------------------------------------------
     def run(self, executor, **arrays):
         """Asynchronous execution request (paper Table 1). Returns a Future."""
         return executor.run(self, **arrays)

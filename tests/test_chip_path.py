@@ -13,7 +13,6 @@ import textwrap
 import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -155,9 +154,14 @@ def test_no_bytes_are_handed_when_every_unit_runs_on_the_host(name):
 def test_the_accelerator_slot_time_splits_into_compute_and_writeback():
     from repro.core import kernel, vector
 
-    def slow(x):
+    def wait(v):
         time.sleep(0.1)
-        return jnp.asarray(x) * 2.0
+        return v
+
+    def slow(x):
+        # a host callback inside the compiled body: every run waits
+        x = jax.pure_callback(wait, jax.ShapeDtypeStruct(x.shape, x.dtype), x)
+        return x * 2.0
     sct = kernel(slow, name="slow_double", inputs=[vector("x")],
                  outputs=[vector("z")])
     sched, telemetry = _bytes_scheduler()

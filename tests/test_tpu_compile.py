@@ -1,5 +1,6 @@
-"""Compile the main path's Pallas kernels and the FFT SCT body for a TPU
-v5e at the sizes ``chip_smoke.py`` runs, without a chip attached.
+"""Compile the main path's Pallas kernels, the FFT SCT body and the filter
+SCT's compiled program for a TPU v5e at the sizes ``chip_smoke.py`` and
+the benchmark run, without a chip attached.
 
 The topology is described inside a module-scoped fixture, never at import:
 only the worker that runs these tests loads the TPU compiler.  Nothing
@@ -12,7 +13,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from benchmarks.paper_suite import FFT_ELEMS, SEG_PLANE, fft_sct
+from benchmarks.paper_suite import (FFT_ELEMS, SEG_PLANE, fft_sct,
+                                    filter_pipeline_sct)
 from chip_smoke import KERNEL_SIZES, SIZES
 from repro.kernels import ops
 
@@ -68,6 +70,20 @@ def test_fft_sct_body_compiles_for_v5e(one_chip):
     text = _compile_text(lambda sig: sct.apply({"sig": sig})["sig_out"],
                          (SIZES["fft"], FFT_ELEMS), sharding=one_chip)
     assert "fft" in text.lower()
+
+
+def test_filter_sct_body_compiles_for_v5e_as_one_program(one_chip):
+    """The chip slot's segment of ``filter.4096.closed`` (3277 of 4096
+    rows at the default split) as the executor runs it: the whole
+    pipeline one program, the first row a traced operand."""
+    rows, width = 3277, 4096
+    img = jax.ShapeDtypeStruct((rows, width), jnp.float32, sharding=one_chip)
+    row0 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    lowered = filter_pipeline_sct(width).program().lower({"img": img}, row0,
+                                                         rows)
+    assert sorted(lowered.out_info) == ["noisy", "out", "sol"]
+    text = lowered.compile().as_text()
+    assert "rev" in text and "s32[]" in text
 
 
 #: output shapes the accelerator slot cuts before its read-back: one filter
